@@ -2,7 +2,9 @@
 
   PYTHONPATH=src python -m benchmarks.run [--quick] [--only gmr_error,...]
 
-Prints ``name,us_per_call,derived`` CSV rows (the skeleton contract).
+Prints ``name,us_per_call,derived`` CSV rows (the skeleton contract). A
+module that raises prints a ``<module>/ERROR`` row, the remaining modules
+still run, and the harness then exits non-zero.
 """
 
 from __future__ import annotations
@@ -18,6 +20,9 @@ def main() -> None:
     ap.add_argument("--only", default="", help="comma-separated module subset")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         cur_decomp,
         gmr_error,
@@ -44,17 +49,21 @@ def main() -> None:
         modules = {k: v for k, v in modules.items() if k in keep}
 
     print("name,us_per_call,derived")
+    failed = []
     for name, mod in modules.items():
         t0 = time.time()
         try:
             rows = mod.run(quick=args.quick)
         except Exception as e:  # noqa: BLE001 — surface per-module failures in CSV
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}")
+            failed.append(name)
             continue
         for row in rows:
             derived = str(row["derived"]).replace(",", ";")
             print(f"{row['name']},{row['us_per_call']},{derived}")
         print(f"{name}/_total,{(time.time()-t0)*1e6:.0f},module_wall_time", file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmark modules failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -62,14 +62,14 @@ def _panel_update_traffic(s_c, m, L, c, s_r, block_m=256, dtype_bytes=4):
     Unfused: ``sc_a`` is written once and read back three times (energy,
     Qᵀ projection, M fold), the candidate columns of ``A_L`` are gathered a
     second time for the C scatter, and C/M each make a full read+write
-    round-trip through XLA's scatter. Fused: ``sc_a`` stays VMEM-resident
-    (written once as an output, zero read-backs), ``A_L`` tiles are read at
-    most twice (sketch reduction + the C write of admitted row blocks), and
-    C/M are aliased in place — C traffic is the admitted row-blocks'
-    read+write, counted here at the full ``m·c`` worst case.
+    round-trip through XLA's scatter. Fused: ``sc_a`` is written once and
+    read back once by the XLA ``M`` fold, ``A_L`` tiles are read at most
+    twice (sketch reduction + the C write of admitted row blocks), and C is
+    aliased in place — C traffic is the admitted row-blocks' read+write,
+    counted here at the full ``m·c`` worst case.
     """
     fused = (2 * m * L + s_c * m + s_c * c + L * s_r + 2 * s_c * s_r
-             + 2 * m * c + s_c * L + 2 * 8 * L) * dtype_bytes
+             + 2 * m * c + 2 * s_c * L + 2 * 8 * L) * dtype_bytes
     unfused = (2 * m * L + s_c * m + s_c * c + L * s_r + 2 * s_c * s_r
                + 2 * m * c + 4 * s_c * L + c * L + 2 * L) * dtype_bytes
     return fused, unfused
@@ -168,7 +168,7 @@ def run(trials: int = 3, quick: bool = False) -> list:
             "us_per_call": round(us_ref, 1),
             "derived": f"pallas_rel_err={rel:.2e};slots_exact={slots_equal};"
                        f"hbm_fused={fused/1e6:.1f}MB;hbm_unfused={unfused/1e6:.1f}MB;"
-                       f"traffic_save={unfused/fused:.2f}x;sc_a_hbm_roundtrips=0vs3",
+                       f"traffic_save={unfused/fused:.2f}x;sc_a_hbm_readbacks=1vs3",
         })
 
     cs_shapes = [(256, 4096, 1024)] if quick else [(128, 2048, 512), (256, 4096, 1024), (512, 8192, 2048)]

@@ -1,14 +1,11 @@
-"""Batched serving example: prefill + decode a smoke-scale model on an
-8-device (data×model) mesh.
+"""Batched serving example: prefill + decode a smoke-scale model on every
+visible device (data-parallel mesh).
 
   PYTHONPATH=src python examples/serve_lm.py [--arch gemma3-12b]
 """
 
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
+import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -24,7 +21,7 @@ def main():
     args = ap.parse_args()
     serve_mod.main([
         "--arch", args.arch, "--smoke",
-        "--batch", "4", "--prompt-len", "48", "--gen", "24", "--mesh", "4x2",
+        "--batch", "4", "--prompt-len", "48", "--gen", "24",
         "--kv-compress", str(args.kv_compress),
     ])
     print("serve_lm example OK")

@@ -251,41 +251,6 @@ def activation_sharding(mesh: Mesh, rules: ParallelismRules):
         _ACT_CTX.reset(tok)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=True):
-    """``jax.shard_map`` across jax versions.
-
-    Modern jax exposes ``jax.shard_map(..., axis_names=, check_vma=)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., auto=, check_rep=)``
-    where ``auto`` is the complement of the manual ``axis_names``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset() if axis_names is None else frozenset(mesh.axis_names) - frozenset(axis_names)
-    if auto:
-        # partial-auto on 0.4.x dies deep in the partitioner (bare
-        # NotImplementedError / XLA tile-validation errors) — fail loud here
-        raise NotImplementedError(
-            f"partial-auto shard_map (manual={sorted(frozenset(axis_names))}, "
-            f"auto={sorted(auto)}) requires jax >= 0.6; this jax only supports "
-            "fully-manual shard_map"
-        )
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
-def axis_size_compat(axis_name) -> int:
-    """Size of a named mesh axis inside shard_map, across jax versions."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def shard_act(x, kind: str):
     """with_sharding_constraint by semantic kind; no-op outside the context
     and for dims not divisible by their assigned axes. Axes the value is
@@ -299,9 +264,7 @@ def shard_act(x, kind: str):
     layout = _ACT_KINDS[kind]
     if x.ndim < len(layout):
         return x
-    typeof = getattr(jax, "typeof", None)  # absent pre-0.6: no VMA tracking
-    manual = getattr(typeof(x), "vma", frozenset()) if typeof else frozenset()
-    if manual:
+    if jax.typeof(x).vma:
         # inside a shard_map manual region constraints over the (auto-typed)
         # mesh are rejected for vma-carrying values; the partial-auto
         # partitioner propagates TP shardings from the parameters instead

@@ -26,12 +26,12 @@ def _kernel(h_ref, sg_ref, a_ref, out_ref, acc_ref, *, s_pad: int):
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    h = h_ref[...]  # (bm,) int32
-    sg = sg_ref[...]  # (bm,)
-    bm = h.shape[0]
+    h = h_ref[...]  # (1, bm) int32
+    sg = sg_ref[...]  # (1, bm)
+    bm = h.shape[1]
     # signed one-hot slab (s_pad, bm) built in-register: rows=sketch buckets
     rows = jax.lax.broadcasted_iota(jnp.int32, (s_pad, bm), 0)
-    slab = jnp.where(rows == h[None, :], sg[None, :], 0).astype(a_ref.dtype)
+    slab = jnp.where(rows == h, sg, 0).astype(a_ref.dtype)
     acc_ref[...] += jnp.dot(slab, a_ref[...], preferred_element_type=jnp.float32)
 
     @pl.when(k == pl.num_programs(1) - 1)
@@ -40,8 +40,8 @@ def _kernel(h_ref, sg_ref, a_ref, out_ref, acc_ref, *, s_pad: int):
 
 
 def countsketch_kernel(
-    hashes: jax.Array,  # (m,) int32 in [0, s)
-    signs: jax.Array,  # (m,) ±1
+    hashes: jax.Array,  # (1, m) int32 in [0, s)
+    signs: jax.Array,  # (1, m) ±1
     a: jax.Array,  # (m, n)
     s: int,
     *,
@@ -49,7 +49,12 @@ def countsketch_kernel(
     block_n: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
-    """dims must be pre-padded to block multiples; s padded to 128 (ops.py)."""
+    """dims must be pre-padded to block multiples; s padded to 128 (ops.py).
+
+    Hashes and signs travel as ``(1, m)`` rows: a 1-D ``(block_m,)`` block
+    does not match the layout XLA gives a 1-D int32 array on TPU, while a
+    ``(1, block_m)`` block is a full-extent sublane × lane-aligned tile.
+    """
     m, n = a.shape
     assert m % block_m == 0 and n % block_n == 0 and s % 128 == 0
     grid = (n // block_n, m // block_m)
@@ -60,8 +65,8 @@ def countsketch_kernel(
         functools.partial(_kernel, s_pad=s),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_m,), lambda j, k: (k,)),
-            pl.BlockSpec((block_m,), lambda j, k: (k,)),
+            pl.BlockSpec((1, block_m), lambda j, k: (0, k)),
+            pl.BlockSpec((1, block_m), lambda j, k: (0, k)),
             pl.BlockSpec((block_m, block_n), lambda j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((s, block_n), lambda j, k: (0, j)),

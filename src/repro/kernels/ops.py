@@ -125,7 +125,8 @@ def countsketch_apply(
         hp = hp.at[m:].set(filler)
     (sgp,) = pad_dims((signs, (block_m,)))  # zero signs ⇒ padded rows contribute 0
     out = countsketch_kernel(
-        hp, sgp, ap, s_pad, block_m=block_m, block_n=block_n, interpret=interpret
+        hp[None, :], sgp[None, :], ap, s_pad,
+        block_m=block_m, block_n=block_n, interpret=interpret,
     )
     return out[:s, : n]
 
@@ -180,26 +181,26 @@ def panel_update(
     block_m: int = 256,
     interpret: bool | None = None,
 ) -> tuple:
-    """Fused per-panel megakernel: sketch + scores + admission + C/M writes.
+    """Fused per-panel megakernel: sketch + scores + admission + C write.
 
     One VMEM pass per panel of the adaptive admission-only update
     (:mod:`repro.stream.adaptive`): computes ``sc_a = S_C·A_L`` and the
     per-column ``(resid2, energy)`` scores (the ``panel_score`` math),
     resolves the admission *inside the kernel* (eligibility threshold +
     rank-based slot assignment, provably the same selection as the XLA
-    ``top_k``/cumsum path), folds ``M += sc_a · S_Rᵀ`` from the
-    still-resident tile, and scatters the admitted panel columns into ``C``
-    via a one-hot matmul — ``sc_a`` never makes an HBM round-trip and each
-    ``A_L`` tile is read at most twice (once for the sketch reduction, once
-    for the C write of its row block).
+    ``top_k``/cumsum path), and scatters the admitted panel columns into
+    ``C`` via a one-hot matmul — each ``A_L`` tile is read at most twice
+    (once for the sketch reduction, once for the C write of its row block).
+    The ``M += sc_a · S_Rᵀ`` fold runs as one XLA matmul on the kernel's
+    ``sc_a`` output (see :mod:`repro.kernels.panel_update`).
 
     Args:
         sc: ``(s_c, m)`` dense column sketch.
         a_l: ``(m, L)`` panel.
         srt: ``(L, s_r)`` dense transposed S_R window at this panel's offset.
         q: ``(s_c, c_local)`` whitened basis of the admitted sketches.
-        C, M: accumulators; returned updated (buffers are aliased through
-            the kernel, so on TPU the update is in place).
+        C, M: accumulators; returned updated (``C`` is aliased through
+            the kernel, so on TPU its update is in place).
         min_gain, run_mean, true_cols: admission threshold scalars —
             ``thresh = min_gain · max(run_mean, Σenergy/true_cols)``.
         n_filled, free: next free slot and remaining budget of the calling
@@ -216,28 +217,27 @@ def panel_update(
     s_c, m = sc.shape
     L = a_l.shape[1]
     c_total = C.shape[1]
-    s_r = srt.shape[1]
-    scp, ap, srtp, qp, Cp, Mp = pad_dims(
+    scp, ap, qp, Cp = pad_dims(
         (sc, (SUBLANE, block_m)),
         (a_l, (block_m, LANE)),
-        (srt, (LANE, LANE)),
         (q, (SUBLANE, LANE)),
         (C, (block_m, LANE)),
-        (M, (SUBLANE, LANE)),
     )
     scal_f = jnp.zeros((8,), jnp.float32)
     scal_f = scal_f.at[0].set(min_gain).at[1].set(run_mean).at[2].set(true_cols)
     scal_i = jnp.zeros((8,), jnp.int32)
     scal_i = scal_i.at[0].set(n_filled).at[1].set(free)
-    Cp, Mp, sc_a, stats, slots = panel_update_kernel(
-        scp, ap, srtp, qp, Cp, Mp, scal_f, scal_i,
+    Cp, sc_a, stats, slots = panel_update_kernel(
+        scp, ap, qp, Cp, scal_f, scal_i,
         L=L, c_total=c_total, panel_cap=min(panel_cap, L),
         block_m=block_m, interpret=interpret,
     )
+    sc_a = sc_a[:s_c, :L]
+    M = M + (sc_a @ srt.astype(jnp.float32)).astype(M.dtype)
     return (
         Cp[:C.shape[0], :c_total],
-        Mp[:s_c, :s_r],
-        sc_a[:s_c, :L],
+        M,
+        sc_a,
         stats[0, :L],
         stats[1, :L],
         slots[0, :L],

@@ -38,6 +38,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Scoped-VMEM budget of the scoring kernels. Mosaic's 16 MiB default cannot
+# hold the (s_c, ·) blocks at the Table-2 sketch sizes of c = 256 (s_c =
+# 3840 needs ~17 MiB here and ~41 MiB in panel_update); a v5e core has
+# 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 96 * 2**20
+
 
 def _kernel(sc_ref, a_ref, q_ref, sca_ref, stats_ref, acc_ref):
     l = pl.program_id(1)
@@ -104,4 +110,5 @@ def panel_score_kernel(
         ],
         scratch_shapes=[pltpu.VMEM((s_c, block_l), jnp.float32)],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(sc, a_l, q)

@@ -2,10 +2,10 @@
 
 ``panel_score.py`` fused the three scoring reads of a panel into one VMEM
 pass but still returned ``sc_a`` to HBM for XLA to finish the panel: the
-``M += sc_a · S_Rᵀ`` fold, the admission decision, and the scatter of the
-admitted columns into ``C`` each re-read data the kernel just held in
-registers. This kernel extends the same accumulator pattern to the whole
-admission-only panel update (:mod:`repro.stream.adaptive`):
+admission decision and the scatter of the admitted columns into ``C``
+each re-read data the kernel just held in registers. This kernel extends
+the same accumulator pattern to the admission-only panel update
+(:mod:`repro.stream.adaptive`):
 
 * ``sc_a = S_C · A_L`` accumulated in VMEM scratch across the m-reduction
   (never an HBM round-trip between its producers and consumers);
@@ -24,15 +24,20 @@ admission-only panel update (:mod:`repro.stream.adaptive`):
   residuals followed by ``cumsum`` ranking (``top_k`` breaks ties by
   lower index — the same tie-break the rank formula encodes), at O(L²)
   vector ops instead of a sort;
-* ``M_out = M_in + sc_a · S_Rᵀ|window`` from the resident tile (``M``
-  aliased in/out — updated in place);
 * the admitted columns scattered into ``C`` as a one-hot matmul
   ``C ← C·keep + A_L·P`` with ``P[j, s] = [slot_j = s]`` (the
   ``countsketch.py`` slab idiom — a scatter the MXU can execute), ``C``
   aliased in/out.
 
+The ``M += sc_a · S_Rᵀ|window`` fold stays with XLA in
+``ops.panel_update``: an ``(s_c, s_r)`` block resident in VMEM, in and out,
+does not fit the chip's 128 MiB once ``s_c = s_r = 3840`` (the Table-2
+sizes for ``c = r = 256``), and the fold reads and writes ``M`` once per
+panel either way — outside the kernel it re-reads only the ``(s_c, L)``
+``sc_a``.
+
 Grid ``(2, m/block_m)`` — phase-major, m-blocks fastest. Phase 0 runs the
-m-reduction and, on its last step, scores + admission + the M/sc_a/stats
+m-reduction and, on its last step, scores + admission + the sc_a/stats
 writes, parking the slot map in scratch; phase 1 revisits the m-blocks to
 apply the C scatter row-block by row-block (``A_L`` is read once per
 phase — the second read is the unavoidable one: ``C``'s row blocks need
@@ -57,10 +62,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .panel_score import VMEM_LIMIT_BYTES
+
 
 def _kernel(
-    sc_ref, a_ref, srt_ref, q_ref, cin_ref, min_ref, sf_ref, si_ref,
-    cout_ref, mout_ref, sca_ref, stats_ref, slots_ref,
+    sc_ref, a_ref, q_ref, cin_ref, sf_ref, si_ref,
+    cout_ref, sca_ref, stats_ref, slots_ref,
     acc_ref, slot_ref, *, c_total: int, panel_cap: int, L: int,
 ):
     p = pl.program_id(0)
@@ -102,13 +109,20 @@ def _kernel(
             thresh = sf_ref[0] * jnp.maximum(sf_ref[1], panel_mean)
             lane = jax.lax.broadcasted_iota(jnp.int32, (1, Lp), 1)
             eligible = (resid2 > thresh) & (lane < L)
-            # pairwise rank ≡ stable-top_k order (ties broken by lower index)
+            # pairwise rank ≡ stable-top_k order (ties broken by lower index).
+            # The (Lp, 1) column forms are read off the diagonal of the
+            # (Lp, Lp) broadcast — one nonzero term per row, so exact — in
+            # place of a transpose, which Mosaic cannot lower for these
+            # vector shapes.
             ii = jax.lax.broadcasted_iota(jnp.int32, (Lp, Lp), 0)
             jj = jax.lax.broadcasted_iota(jnp.int32, (Lp, Lp), 1)
-            ri = jnp.transpose(resid2)  # (Lp, 1)
-            better = jnp.transpose(eligible) & (
-                (ri > resid2) | ((ri == resid2) & (ii < jj))
-            )
+            diag = ii == jj
+            ri = jnp.sum(jnp.where(diag, resid2, 0.0), axis=1, keepdims=True)
+            ei = jnp.sum(
+                jnp.where(diag, eligible.astype(jnp.float32), 0.0),
+                axis=1, keepdims=True,
+            ) > 0.0
+            better = ei & ((ri > resid2) | ((ri == resid2) & (ii < jj)))
             rank = jnp.sum(better.astype(jnp.int32), axis=0, keepdims=True)
             limit = jnp.minimum(si_ref[1], panel_cap)  # min(free, cap)
             admit = eligible & (rank < limit)
@@ -117,21 +131,29 @@ def _kernel(
             slots_ref[...] = jnp.broadcast_to(slot, slots_ref.shape)
             pad = jnp.zeros((stats_ref.shape[0] - 2, Lp), jnp.float32)
             stats_ref[...] = jnp.concatenate([resid2, energy, pad], axis=0)
-            # M fold from the resident tile: (s_c, Lp) @ (Lp, s_r)
-            mout_ref[...] = min_ref[...] + jnp.dot(
-                y, srt_ref[...], preferred_element_type=jnp.float32
-            ).astype(mout_ref.dtype)
 
     @pl.when(p == 1)
     def _phase1():
-        # scatter-as-matmul (the countsketch slab idiom): P[j, s] = [slot_j = s]
+        # scatter-as-matmul (the countsketch slab idiom), built transposed
+        # from the row-form slot map so no transpose is needed:
+        # PT[s, j] = [slot_j = s]
         slot = slot_ref[0:1, :]  # (1, Lp)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (Lp, cin_ref.shape[1]), 1)
-        P = (jnp.transpose(slot) == cols).astype(jnp.float32)  # (Lp, c_pad)
-        keep = (jnp.sum(P, axis=0, keepdims=True) == 0.0).astype(jnp.float32)
-        newc = jnp.dot(
-            a_ref[...].astype(jnp.float32), P, preferred_element_type=jnp.float32
-        )  # (bm, c_pad) — exact copies: one-hot columns select single A entries
+        c_pad = cin_ref.shape[1]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (c_pad, Lp), 0)
+        PT = (slot == rows).astype(jnp.float32)  # (c_pad, Lp)
+        nt = (((1,), (1,)), ((), ()))  # contract both operands' lane dims
+        hits = jax.lax.dot_general(
+            jnp.ones((8, Lp), jnp.float32), PT, nt,
+            preferred_element_type=jnp.float32,
+        )  # (8, c_pad) — per-slot admission counts (0/1, exact)
+        keep = (hits[0:1, :] == 0.0).astype(jnp.float32)
+        # exact copies: one-hot rows select single A entries, and fp32
+        # contract precision keeps every mantissa bit on the MXU
+        newc = jax.lax.dot_general(
+            a_ref[...].astype(jnp.float32), PT, nt,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )  # (bm, c_pad)
         cout_ref[...] = (
             cin_ref[...].astype(jnp.float32) * keep + newc
         ).astype(cout_ref.dtype)
@@ -143,10 +165,8 @@ def _kernel(
 def panel_update_kernel(
     sc: jax.Array,  # (s_c, m) dense column sketch
     a_l: jax.Array,  # (m, Lp) panel
-    srt: jax.Array,  # (Lp, s_r) dense transposed S_R window at this offset
     q: jax.Array,  # (s_c, c_q) zero-masked whitened basis of admitted sketches
     C: jax.Array,  # (m, c_pad) column factor — aliased to the first output
-    M: jax.Array,  # (s_c, s_r) core sketch — aliased to the second output
     scal_f: jax.Array,  # (8,) f32 [min_gain, run_mean, true_cols, …]
     scal_i: jax.Array,  # (8,) i32 [n_filled, free, …]
     *,
@@ -158,17 +178,15 @@ def panel_update_kernel(
 ) -> tuple:
     """All dims must already be padded to their block multiples (see ops.py).
 
-    Returns ``(C', M', sc_a (s_c, Lp) f32, stats (8, Lp) f32, slots (8, Lp)
+    Returns ``(C', sc_a (s_c, Lp) f32, stats (8, Lp) f32, slots (8, Lp)
     i32)`` with ``stats[0] = resid2``, ``stats[1] = energy`` and
     ``slots[0]`` the per-column admission slot (``c_total`` sentinel).
     """
     s_c, m = sc.shape
     _, Lp = a_l.shape
-    s_r = srt.shape[1]
     c_pad = C.shape[1]
-    assert a_l.shape[0] == m and q.shape[0] == s_c and srt.shape[0] == Lp
-    assert C.shape[0] == m and M.shape == (s_c, s_r)
-    assert s_c % 8 == 0 and Lp % 128 == 0 and s_r % 128 == 0
+    assert a_l.shape[0] == m and q.shape[0] == s_c and C.shape[0] == m
+    assert s_c % 8 == 0 and Lp % 128 == 0
     assert q.shape[1] % 128 == 0 and c_pad % 128 == 0 and m % block_m == 0
 
     grid = (2, m // block_m)
@@ -179,23 +197,19 @@ def panel_update_kernel(
         in_specs=[
             pl.BlockSpec((s_c, block_m), lambda p, k: (0, k)),
             pl.BlockSpec((block_m, Lp), lambda p, k: (k, 0)),
-            pl.BlockSpec((Lp, s_r), lambda p, k: (0, 0)),
             pl.BlockSpec((s_c, q.shape[1]), lambda p, k: (0, 0)),
             pl.BlockSpec((block_m, c_pad), lambda p, k: (k, 0)),
-            pl.BlockSpec((s_c, s_r), lambda p, k: (0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((block_m, c_pad), lambda p, k: (k, 0)),
-            pl.BlockSpec((s_c, s_r), lambda p, k: (0, 0)),
             pl.BlockSpec((s_c, Lp), lambda p, k: (0, 0)),
             pl.BlockSpec((8, Lp), lambda p, k: (0, 0)),
             pl.BlockSpec((8, Lp), lambda p, k: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(C.shape, C.dtype),
-            jax.ShapeDtypeStruct(M.shape, M.dtype),
             jax.ShapeDtypeStruct((s_c, Lp), jnp.float32),
             jax.ShapeDtypeStruct((8, Lp), jnp.float32),
             jax.ShapeDtypeStruct((8, Lp), jnp.int32),
@@ -204,6 +218,7 @@ def panel_update_kernel(
             pltpu.VMEM((s_c, Lp), jnp.float32),
             pltpu.VMEM((8, Lp), jnp.int32),
         ],
-        input_output_aliases={4: 0, 5: 1},
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(sc, a_l, srt, q, C, M, scal_f, scal_i)
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
+    )(sc, a_l, q, C, scal_f, scal_i)

@@ -1,8 +1,10 @@
 """Batched serving driver: prefill + decode loop with timing.
 
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --smoke \\
-      --batch 4 --prompt-len 64 --gen 32 --mesh 4x2
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \\
+      --batch 8 --prompt-len 1024 --gen 32 [--kv-compress 16]
+
+``--smoke`` swaps the published widths for the architecture's smoke preset.
+The mesh defaults to every visible device on the data axis (``Nx1``).
 """
 
 from __future__ import annotations
@@ -12,9 +14,11 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs import get_arch
 from repro.distributed.sharding import ParallelismRules, activation_sharding, param_shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, param_count
 from repro.models.modality import synth_patch_embeddings
 from repro.serve import KVCompressionConfig, generate
@@ -23,12 +27,12 @@ from repro.serve import KVCompressionConfig, generate
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--mesh", default="4x2")
+    ap.add_argument("--mesh", default=None, help="dataxmodel, e.g. 4x1 (default: Nx1)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--kv-compress", type=int, default=0, metavar="RANK",
                     help="compress full-attention KV caches at this rank "
@@ -44,8 +48,11 @@ def main(argv=None):
                                  adaptive=args.kv_adaptive,
                                  min_rank=max(1, args.kv_compress // 4))
 
-    d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    enable_compile_cache()
+    d, m = (int(x) for x in (args.mesh or f"{jax.device_count()}x1").split("x"))
+    # Auto axes: the sharding rules place parameters and constrain
+    # activations; explicit-typed axes would reject the embedding gather
+    mesh = jax.make_mesh((d, m), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     rules = ParallelismRules(dp_axes=("data",))
     mod = get_arch(args.arch)
     cfg = mod.smoke_config() if args.smoke else mod.full_config()

@@ -20,7 +20,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 
 from repro.checkpoint import run_resilient_loop
 from repro.configs import get_arch
@@ -31,6 +31,7 @@ from repro.distributed.sharding import (
     batch_pspec,
     param_shardings,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, param_count
 from repro.train import (
     CompressionConfig,
@@ -85,7 +86,7 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--mesh", default="4x2", help="dataxmodel, e.g. 4x2")
+    ap.add_argument("--mesh", default=None, help="dataxmodel, e.g. 4x2 (default: Nx1)")
     ap.add_argument("--remat", default="dots", choices=["dots", "full", "none"])
     ap.add_argument("--microbatch", type=int, default=1)
     ap.add_argument("--grad-compress", action="store_true")
@@ -97,8 +98,11 @@ def main(argv=None):
     ap.add_argument("--fail-at-step", type=int, default=-1, help="inject a crash (FT demo)")
     args = ap.parse_args(argv)
 
-    d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    enable_compile_cache()
+    d, m = (int(x) for x in (args.mesh or f"{jax.device_count()}x1").split("x"))
+    # Auto axes: the sharding rules place parameters and constrain
+    # activations; explicit-typed axes would reject the embedding gather
+    mesh = jax.make_mesh((d, m), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     rules = ParallelismRules(dp_axes=("data",))
     cfg = build_config(args)
 

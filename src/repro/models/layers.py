@@ -28,11 +28,8 @@ def match_vma(x, ref):
     gradient step) the carry must be marked varying over the manual axes its
     inputs vary over. No-op outside shard_map.
     """
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:  # older jax: no VMA tracking, carries need no marking
-        return x
-    extra = typeof(ref).vma - typeof(x).vma
-    return jax.lax.pvary(x, tuple(extra)) if extra else x
+    extra = jax.typeof(ref).vma - jax.typeof(x).vma
+    return jax.lax.pcast(x, tuple(extra), to="varying") if extra else x
 
 
 # ---------------------------------------------------------------------------

@@ -26,10 +26,17 @@ from .kv_cache import compress_prefill_cache
 
 
 def sample_token(key, logits: jax.Array, temperature: float = 0.0) -> jax.Array:
-    """logits (B, 1, V) → (B, 1) int32."""
+    """logits (B, 1, V) → (B, 1) int32.
+
+    A row with any non-finite logit samples ``-1``, an id outside the
+    vocabulary, so corrupt logits surface in the output instead of as a
+    plausible token (``argmax`` over NaNs would return 0).
+    """
     if temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jax.random.categorical(key, logits[:, 0] / temperature)[:, None].astype(jnp.int32)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    else:
+        tok = jax.random.categorical(key, logits[:, 0] / temperature)[:, None].astype(jnp.int32)
+    return jnp.where(jnp.all(jnp.isfinite(logits), axis=-1), tok, -1)
 
 
 @partial(jax.jit, static_argnums=(1, 6, 7), donate_argnums=(2,))

@@ -51,7 +51,6 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..distributed.sharding import shard_map_compat
 from ..obs.spans import span
 from .engine import PanelState, padded_n, scan_chunk, scan_panels, stream_panels
 
@@ -270,7 +269,6 @@ def mesh_sharded_stream(
             f"padded column count {n_pad} must split into whole panels per "
             f"worker (W={W}, panel={panel})"
         )
-    shard_n = n_pad // W
     if A.shape[1] != n_pad:
         A = jnp.pad(A, ((0, 0), (0, n_pad - A.shape[1])))
     if state0.R.shape[1] != n_pad:
@@ -280,8 +278,19 @@ def mesh_sharded_stream(
     if ops.prep_shard is not None:
         ctx0 = ops.prep_shard(ctx0, W)
     state0 = dataclasses.replace(state0, ctx=ctx0)
+    with span(f"stream/{ops.name}/sharded_mesh"):
+        return _mesh_stream(state0, A, panel=panel, mesh=mesh, axis=axis)
 
+
+@partial(jax.jit, static_argnames=("panel", "mesh", "axis"))
+def _mesh_stream(state0: PanelState, A: jax.Array, *, panel: int, mesh, axis: str) -> PanelState:
+    """The compiled ``shard_map`` program of :func:`mesh_sharded_stream`
+    (module scope, so repeated chunks of one stream reuse one compile)."""
     from jax.sharding import PartitionSpec as P
+
+    ops = state0.ops
+    n = state0.n
+    shard_n = A.shape[1] // int(mesh.shape[axis])
 
     def body(state, A_shard):
         w = jax.lax.axis_index(axis)
@@ -311,14 +320,11 @@ def mesh_sharded_stream(
         )
         return ops.merge_state(st) if ops.merge_state is not None else st
 
-    state_specs = jax.tree_util.tree_map(lambda _: P(), state0)
-    out_specs = jax.tree_util.tree_map(lambda _: P(), state0)
-    f = shard_map_compat(
+    specs = jax.tree_util.tree_map(lambda _: P(), state0)
+    return jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(state_specs, P(None, axis)),
-        out_specs=out_specs,
+        in_specs=(specs, P(None, axis)),
+        out_specs=specs,
         check_vma=False,
-    )
-    with span(f"stream/{ops.name}/sharded_mesh"):
-        return f(state0, A)
+    )(state0, A)

@@ -30,7 +30,6 @@ import numpy as np
 
 from repro.core.gmr import fast_gmr_core
 from repro.core.sketching import draw_sketch
-from repro.distributed.sharding import axis_size_compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,7 +152,7 @@ def compressed_mean_grads(
     """
     nworkers = 1
     for a in axes:
-        nworkers *= axis_size_compat(a)
+        nworkers *= jax.lax.axis_size(a)
 
     flat, tdef = jax.tree.flatten(grads)
     flat_err = tdef.flatten_up_to(err)

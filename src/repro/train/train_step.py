@@ -23,10 +23,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import (
     ParallelismRules,
-    axis_size_compat,
     batch_pspec,
     param_pspecs,
-    shard_map_compat,
 )
 from repro.models import train_logits
 from repro.models.config import ModelConfig
@@ -163,7 +161,7 @@ def make_compressed_train_step(
         params, opt, opt_metrics = adamw_update(gbar, opt, params, oc)
         nw = 1
         for a in dp:
-            nw *= axis_size_compat(a)
+            nw *= jax.lax.axis_size(a)
         # psum local metrics so every output except `err` is dp-invariant
         # (check_vma=True verifies this; partial-manual + check_vma=False is
         # broken in jax 0.8.2 — see DESIGN.md §Environment). The per-step
@@ -189,7 +187,7 @@ def make_compressed_train_step(
             "comp/wire_floats", "comp/dense_floats", "comp/ratio",
             "comp/ef_norm", "comp/rel_err",
         )
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(pspec, ospec, espec, bspec, P()),

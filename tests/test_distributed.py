@@ -86,21 +86,11 @@ def test_multidev_sharded_equals_single():
     _run_scenario("sharded")
 
 
-# The compressed-gradient scenarios run shard_map *partial-auto* (manual dp,
-# auto model axis). On jax < 0.6 XLA rejects replicated rank-1 inputs (the
-# PRNG key) under partial-auto tile validation — the feature generation this
-# code targets simply isn't present; skip rather than exercise known-broken
-# partitioner paths.
-_PARTIAL_AUTO_OK = hasattr(jax, "shard_map")
-
-
 @pytest.mark.slow
-@pytest.mark.skipif(not _PARTIAL_AUTO_OK, reason="partial-auto shard_map unsupported on this jax")
 def test_multidev_compressed_converges():
     _run_scenario("compressed")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not _PARTIAL_AUTO_OK, reason="partial-auto shard_map unsupported on this jax")
 def test_multidev_compressed_wire_bytes():
     _run_scenario("wire")

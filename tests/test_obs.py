@@ -352,7 +352,6 @@ def test_kv_compress_metrics():
 
 
 def test_grad_compress_stats():
-    from repro.distributed.sharding import shard_map_compat
     from repro.train.grad_compress import CompressionConfig, compressed_mean_grads
 
     ccfg = CompressionConfig(rank=8, sketch_factor=2, min_dim=64)
@@ -374,7 +373,7 @@ def test_grad_compress_stats():
         return stats
 
     spec = jax.tree.map(lambda _: P(), g)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         f, mesh=mesh, in_specs=(spec, spec, P()),
         out_specs={k: P() for k in stat_keys}, axis_names={"dp"}, check_vma=True,
     )

@@ -1,0 +1,84 @@
+"""Ahead-of-time compiles of the four Pallas kernels for a described v5e chip.
+
+Interpret mode (every other kernel test) cannot see what the chip's
+compiler refuses: misaligned block layouts, in-kernel transposes Mosaic
+cannot lower, blocks that overflow VMEM. These tests hand the real TPU
+compiler shapes at the stream widths and assert each program holds a Mosaic
+kernel (``tpu_custom_call``) — nothing runs, so no chip is needed.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+# (m, L, s_c, c): panel height, panel width, column-sketch size, column
+# budget. The last row is the chip smoke's stream (32768² at panel 512,
+# c = r = 256 with the Table-2 sketch sizes s_c = s_r = 3840).
+WIDTHS = [(2048, 256, 160, 16), (32768, 256, 160, 16), (32768, 512, 3840, 256)]
+KERNELS = ["twoside_sketch", "countsketch_apply", "panel_score", "panel_update"]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _kernel_call(name, m, L, s_c, c, shape):
+    """(function, argument shapes) compiling one ``ops`` wrapper as Mosaic."""
+    f32, i32 = jnp.float32, jnp.int32
+    if name == "twoside_sketch":
+        return partial(ops.twoside_sketch, interpret=False), [
+            shape((s_c, m), f32), shape((m, L), f32), shape((L, s_c), f32)]
+    if name == "countsketch_apply":
+        return partial(ops.countsketch_apply, s=s_c, interpret=False), [
+            shape((m,), i32), shape((m,), f32), shape((m, L), f32)]
+    if name == "panel_score":
+        return partial(ops.panel_score, interpret=False), [
+            shape((s_c, m), f32), shape((m, L), f32), shape((s_c, c), f32)]
+
+    def update(sc, a_l, srt, q, C, M, sf, si):
+        return ops.panel_update(
+            sc, a_l, srt, q, C, M, min_gain=sf[0], run_mean=sf[1], true_cols=sf[2],
+            n_filled=si[0], free=si[1], panel_cap=max(1, c // 8), interpret=False,
+        )
+
+    return update, [
+        shape((s_c, m), f32), shape((m, L), f32), shape((L, s_c), f32),
+        shape((s_c, c), f32), shape((m, c), f32), shape((s_c, s_c), f32),
+        shape((3,), f32), shape((2,), i32)]
+
+
+@pytest.mark.parametrize("width", WIDTHS, ids=lambda w: "m{}_L{}_sc{}_c{}".format(*w))
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e(one_chip, name, width):
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    fn, args = _kernel_call(name, *width, shape)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
