@@ -37,8 +37,10 @@ import jax.numpy as jnp
 
 from ..core.gmr import fast_gmr_core
 from ..core.sketching import draw_sketch
+from ..obs.spans import spanned
 from ..obs.telemetry import fixed_stream_telemetry, init_telemetry
 from ..stream.engine import (
+    SCOPE_SOLVE,
     PanelOps,
     PanelState,
     copy_selected_columns,
@@ -130,6 +132,7 @@ STREAMING_CUR_TEL_OPS = dataclasses.replace(
 StreamingCURState = PanelState
 
 
+@spanned("stream/streaming_cur/init")
 def streaming_cur_init(
     key,
     m: int,
@@ -236,13 +239,14 @@ def streaming_cur_finalize(state: StreamingCURState) -> CURResult:
     order.
     """
     ctx = state.ctx
-    R = truncated_R(state)
-    ScC = ctx.S_C.apply(state.C)  # (s_c, c)
-    RSr = ctx.S_R.apply_t(R)  # (r, s_r)
-    U = fast_gmr_core(ScC, state.M, RSr)
+    with jax.named_scope(SCOPE_SOLVE):
+        R = truncated_R(state)
+        ScC = ctx.S_C.apply(state.C)  # (s_c, c)
+        RSr = ctx.S_R.apply_t(R)  # (r, s_r)
+        U = fast_gmr_core(ScC, state.M, RSr)
     return CURResult(C=state.C, U=U, R=R, col_idx=ctx.col_idx, row_idx=ctx.row_idx)
 
 
-# Compiled at module scope (one trace per shape); the state is NOT donated —
-# callers inspect it after finalizing.
-streaming_cur_finalize = jax.jit(streaming_cur_finalize)
+# Compiled at module scope (one trace per shape) and dispatched inside a host
+# span; the state is NOT donated — callers inspect it after finalizing.
+streaming_cur_finalize = spanned("stream/streaming_cur/finalize")(jax.jit(streaming_cur_finalize))

@@ -12,9 +12,10 @@ Three layers, strictly opt-in at every level:
   ``Ψ = A Ω_test`` the telemetry frame maintains in-stream (Tropp et al.'s
   a-posteriori argument; no second pass over ``A``).
 * :mod:`repro.obs.metrics` / :mod:`repro.obs.spans` — a host-side registry
-  of counters/gauges/histograms with a JSON-lines dump, and
-  ``jax.profiler``-annotated wall-clock spans with a ``render_timeline``
-  report; the process default registry starts disabled.
+  of counters/gauges/histograms with a JSON-lines dump, and host spans that
+  always annotate the ``jax.profiler`` trace and are recorded (with their
+  parent span) when the registry is enabled; the process default registry
+  starts disabled.
 
 Enable per stream with ``telemetry=True`` on the plug-in inits
 (``adaptive_cur_init``, ``streaming_cur_init``, ``streaming_spsd_init``,
@@ -24,7 +25,7 @@ catalog and the estimator derivation.
 
 from .error_estimate import estimate_rel_error, low_rank_apply
 from .metrics import MetricsRegistry, SpanRecord, default_registry, set_registry
-from .spans import render_timeline, span
+from .spans import span
 from .telemetry import (
     EVENT_ADMIT,
     EVENT_BUDGET_FULL,
@@ -55,6 +56,5 @@ __all__ = [
     "SpanRecord",
     "default_registry",
     "set_registry",
-    "render_timeline",
     "span",
 ]

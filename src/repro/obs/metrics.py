@@ -16,7 +16,7 @@ Three instrument kinds, all keyed by a flat string name (convention:
   count/mean/min/p50/p90/max (:meth:`MetricsRegistry.observe`).
 
 The registry also collects the span records emitted by
-:func:`repro.obs.spans.span` (wall-clock + nesting depth) — one shared sink
+:func:`repro.obs.spans.span` (wall-clock + parent span) — one shared sink
 so a single ``dump_jsonl`` captures the whole run.
 
 The module-level default registry starts **disabled**: every instrument
@@ -45,17 +45,19 @@ __all__ = [
 
 @dataclasses.dataclass
 class SpanRecord:
-    """One closed :func:`repro.obs.spans.span`: wall-clock + nesting depth.
+    """One closed :func:`repro.obs.spans.span`: wall-clock + parent span.
 
     ``start`` is seconds since the registry's epoch (its construction time),
     ``duration`` seconds of host wall-clock — dispatch time, not device time,
-    unless the caller blocked on the result inside the span.
+    unless the caller blocked on the result inside the span. ``parent`` is
+    the name of the span that was open around it (``None`` at top level), so
+    a layer's self time is its duration less its children's.
     """
 
     name: str
     start: float
     duration: float
-    depth: int
+    parent: Optional[str]
 
 
 class MetricsRegistry:
@@ -73,7 +75,7 @@ class MetricsRegistry:
         self.histograms: dict = {}
         self.spans: list = []
         self.epoch = time.perf_counter()
-        self._span_stack: list = []  # open span names (depth tracking)
+        self._span_stack: list = []  # open span names (parent tracking)
 
     def inc(self, name: str, value: float = 1) -> None:
         """Add ``value`` to counter ``name`` (created at 0)."""
@@ -169,7 +171,7 @@ class MetricsRegistry:
                 "name": s.name,
                 "start_s": round(s.start, 6),
                 "duration_s": round(s.duration, 6),
-                "depth": s.depth,
+                "parent": s.parent,
             }
             for s in self.spans
         ]

@@ -1,28 +1,28 @@
-"""Named profiling spans: wall-clock records + ``jax.profiler`` annotations.
+"""Named host spans: ``jax.profiler`` annotations + wall-clock records.
 
-:func:`span` is a context manager instrumenting the host side of a dispatch:
-it pushes a :class:`~repro.obs.metrics.SpanRecord` (start, duration, nesting
-depth) into the active :class:`~repro.obs.metrics.MetricsRegistry` and wraps
-the body in a :class:`jax.profiler.TraceAnnotation`, so the same names show
-up in TensorBoard/perfetto traces when a profiler session is live.
+:func:`span` is a context manager instrumenting the host side of a dispatch.
+It always wraps the body in a :class:`jax.profiler.TraceAnnotation`, so the
+span lands on the profiler's clock next to the device's operations whenever
+a profiler session is live (with none live, the annotation is one check in
+C++). When the active :class:`~repro.obs.metrics.MetricsRegistry` is
+enabled it also records a :class:`~repro.obs.metrics.SpanRecord` (start,
+duration, parent span).
 
 Span naming scheme (see ``docs/observability.md`` for the catalog):
 ``layer/subject/stage`` — e.g. ``stream/adaptive_cur/scan``,
-``stream/adaptive_cur/sharded``, ``serve/kv_compress/prefill``,
-``obs/estimate_rel_error``.
+``stream/adaptive_cur/init``, ``serve/kv_compress/prefill``.
 
 Async-dispatch caveat: JAX returns before the device finishes, so a span
 around a bare jitted call measures dispatch, not execution. Block inside the
 span (``jax.block_until_ready(out)``) when device wall-clock is the thing
-being measured — the benchmark drivers do.
-
-With the default registry disabled the context manager is a no-op ``yield``
-(no clock read, no annotation), so spans baked into library code — the
-engine's scan drivers — cost one attribute check in production.
+being measured. What the device did meanwhile is named by the engine's
+``jax.named_scope`` scopes (``stream.sketch``, ``stream.mfold``, …) in the
+device trace.
 """
 
 from __future__ import annotations
 
+import functools
 import time
 from contextlib import contextmanager
 from typing import Optional
@@ -31,55 +31,40 @@ import jax
 
 from .metrics import MetricsRegistry, SpanRecord, default_registry
 
-__all__ = ["span", "render_timeline"]
+__all__ = ["span", "spanned"]
 
 
 @contextmanager
 def span(name: str, registry: Optional[MetricsRegistry] = None):
-    """Record a named wall-clock span into ``registry`` (default: the
-    process registry) and annotate the profiler trace. No-op when the
-    registry is disabled."""
+    """Annotate the profiler trace with the host span ``name`` and, when
+    ``registry`` (default: the process registry) is enabled, record it."""
     reg = registry if registry is not None else default_registry()
-    if not reg.enabled:
-        yield
-        return
-    depth = len(reg._span_stack)
-    reg._span_stack.append(name)
-    start = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(name):
+    with jax.profiler.TraceAnnotation(name):
+        if not reg.enabled:
             yield
-    finally:
-        duration = time.perf_counter() - start
-        reg._span_stack.pop()
-        reg.spans.append(
-            SpanRecord(name=name, start=start - reg.epoch, duration=duration, depth=depth)
-        )
+            return
+        parent = reg._span_stack[-1] if reg._span_stack else None
+        reg._span_stack.append(name)
+        start = time.perf_counter()
+        try:
+            yield
+        finally:
+            duration = time.perf_counter() - start
+            reg._span_stack.pop()
+            reg.spans.append(
+                SpanRecord(name=name, start=start - reg.epoch, duration=duration, parent=parent)
+            )
 
 
-def render_timeline(registry: Optional[MetricsRegistry] = None, width: int = 40) -> str:
-    """ASCII timeline of the registry's recorded spans.
+def spanned(name: str):
+    """Decorator: run every call of the function inside ``span(name)``."""
 
-    One line per span in start order — indentation shows nesting, the bar
-    shows the span's extent relative to the whole recorded window::
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
 
-        stream/adaptive_cur/scan      12.31ms |   ####             |
-          obs/estimate_rel_error       3.02ms |       ##           |
+        return call
 
-    Returns ``"(no spans recorded)"`` when the registry has none.
-    """
-    reg = registry if registry is not None else default_registry()
-    spans = sorted(reg.spans, key=lambda s: s.start)
-    if not spans:
-        return "(no spans recorded)"
-    t0 = min(s.start for s in spans)
-    t1 = max(s.start + s.duration for s in spans)
-    window = max(t1 - t0, 1e-9)
-    lines = []
-    for s in spans:
-        lo = int((s.start - t0) / window * width)
-        hi = max(int((s.start + s.duration - t0) / window * width), lo + 1)
-        bar = " " * lo + "#" * (hi - lo) + " " * (width - hi)
-        label = "  " * s.depth + s.name
-        lines.append(f"{label:<44} {s.duration * 1e3:>9.2f}ms |{bar}|")
-    return "\n".join(lines)
+    return wrap

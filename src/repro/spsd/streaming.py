@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from ..core.gmr import fast_gmr_core
 from ..core.projections import psd_project
 from ..core.sketching import draw_sketch
+from ..obs.spans import spanned
 from ..obs.telemetry import (
     adaptive_stream_telemetry,
     fixed_stream_telemetry,
@@ -68,6 +69,7 @@ from ..stream.adaptive import (
     _update_c,
 )
 from ..stream.engine import (
+    SCOPE_SOLVE,
     PanelOps,
     PanelState,
     copy_selected_columns,
@@ -213,6 +215,7 @@ def _maybe_telemetry(telemetry: bool, key, n: int, panel, base_ops, tel_ops):
     return init_telemetry(jax.random.fold_in(key, 7), n, n, panel), tel_ops
 
 
+@spanned("stream/streaming_spsd/init")
 def streaming_spsd_init(
     key,
     n: int,
@@ -300,14 +303,16 @@ def streaming_spsd_finalize(state: PanelState) -> SPSDResult:
     query count).
     """
     ctx = state.ctx
-    S1C = ctx.S1.apply(state.C)  # (s, c)
-    CS2 = ctx.S2.apply(state.C).T  # (c, s)
-    X = psd_project(fast_gmr_core(S1C, state.M, CS2))
+    with jax.named_scope(SCOPE_SOLVE):
+        S1C = ctx.S1.apply(state.C)  # (s, c)
+        CS2 = ctx.S2.apply(state.C).T  # (c, s)
+        X = psd_project(fast_gmr_core(S1C, state.M, CS2))
     return SPSDResult(
         C=state.C, X=X, col_idx=ctx.col_idx, entries_observed=state.n * state.n
     )
 
 
+@spanned("stream/adaptive_spsd/init")
 def adaptive_spsd_init(
     key,
     n: int,
@@ -383,19 +388,26 @@ def adaptive_spsd_finalize(state: PanelState) -> SPSDResult:
     matrix keeps it PSD, and zero C columns contribute nothing either way).
     """
     ctx = state.ctx
-    CS2 = ctx.S_R.apply(state.C).T  # (c, s)
-    X = fast_gmr_core(ctx.ScC, state.M, CS2)  # ScC ≡ S₁ C by construction
-    filled = ctx.col_idx >= 0
-    X = jnp.where(filled[:, None] & filled[None, :], X, jnp.zeros((), X.dtype))
+    with jax.named_scope(SCOPE_SOLVE):
+        CS2 = ctx.S_R.apply(state.C).T  # (c, s)
+        X = fast_gmr_core(ctx.ScC, state.M, CS2)  # ScC ≡ S₁ C by construction
+        filled = ctx.col_idx >= 0
+        X = jnp.where(filled[:, None] & filled[None, :], X, jnp.zeros((), X.dtype))
+        X = psd_project(X)
     return SPSDResult(
         C=state.C,
-        X=psd_project(X),
+        X=X,
         col_idx=ctx.col_idx,
         entries_observed=state.n * state.n,
     )
 
 
-# Compiled at module scope (one trace per shape); states are NOT donated —
-# callers inspect them (col_idx, n_evicted, …) after finalizing.
-streaming_spsd_finalize = jax.jit(streaming_spsd_finalize)
-adaptive_spsd_finalize = jax.jit(adaptive_spsd_finalize)
+# Compiled at module scope (one trace per shape) and dispatched inside host
+# spans; states are NOT donated — callers inspect them (col_idx, n_evicted,
+# …) after finalizing.
+streaming_spsd_finalize = spanned("stream/streaming_spsd/finalize")(
+    jax.jit(streaming_spsd_finalize)
+)
+adaptive_spsd_finalize = spanned("stream/adaptive_spsd/finalize")(
+    jax.jit(adaptive_spsd_finalize)
+)

@@ -96,8 +96,9 @@ from ..core.gmr import fast_gmr_core
 from ..core.sketching import GaussianSketch, draw_sketch
 from ..kernels.ops import kernel_route_enabled, panel_score
 from ..kernels.ops import panel_update as kernel_panel_update
+from ..obs.spans import spanned
 from ..obs.telemetry import adaptive_stream_telemetry, init_telemetry
-from .engine import PanelOps, PanelState, fresh_pytree, padded_n, truncated_R
+from .engine import SCOPE_SOLVE, PanelOps, PanelState, fresh_pytree, padded_n, truncated_R
 
 __all__ = [
     "AdaptiveCURCtx",
@@ -840,6 +841,7 @@ ADAPTIVE_CUR_TEL_OPS = dataclasses.replace(
 )
 
 
+@spanned("stream/adaptive_cur/init")
 def adaptive_cur_init(
     key,
     m: int,
@@ -1020,17 +1022,19 @@ def adaptive_cur_finalize(state: PanelState):
     from ..cur.cur import CURResult  # lazy: repro.cur imports repro.stream
 
     ctx = state.ctx
-    R = truncated_R(state)
-    RSr = ctx.S_R.apply_t(R)  # (r, s_r)
-    U = fast_gmr_core(ctx.ScC, state.M, RSr)  # ScC ≡ S_C C by construction
-    filled_c = ctx.col_idx >= 0
-    U = jnp.where(filled_c[:, None], U, jnp.zeros((), U.dtype))
-    if ctx.rows is not None:
-        filled_r = ctx.row_idx >= 0
-        U = jnp.where(filled_r[None, :], U, jnp.zeros((), U.dtype))
+    with jax.named_scope(SCOPE_SOLVE):
+        R = truncated_R(state)
+        RSr = ctx.S_R.apply_t(R)  # (r, s_r)
+        U = fast_gmr_core(ctx.ScC, state.M, RSr)  # ScC ≡ S_C C by construction
+        filled_c = ctx.col_idx >= 0
+        U = jnp.where(filled_c[:, None], U, jnp.zeros((), U.dtype))
+        if ctx.rows is not None:
+            filled_r = ctx.row_idx >= 0
+            U = jnp.where(filled_r[None, :], U, jnp.zeros((), U.dtype))
     return CURResult(C=state.C, U=U, R=R, col_idx=ctx.col_idx, row_idx=ctx.row_idx)
 
 
-# Compiled at module scope (one trace per shape); the state is NOT donated —
-# callers inspect it (n_evicted, admit_off, …) after finalizing.
-adaptive_cur_finalize = jax.jit(adaptive_cur_finalize)
+# Compiled at module scope (one trace per shape) and dispatched inside a host
+# span; the state is NOT donated — callers inspect it (n_evicted, admit_off,
+# …) after finalizing.
+adaptive_cur_finalize = spanned("stream/adaptive_cur/finalize")(jax.jit(adaptive_cur_finalize))

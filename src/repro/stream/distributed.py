@@ -52,7 +52,9 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.spans import span
-from .engine import PanelState, padded_n, scan_chunk, scan_panels, stream_panels
+from .engine import (
+    SCOPE_PSUM, PanelState, padded_n, scan_chunk, scan_panels, stream_panels,
+)
 
 __all__ = [
     "shard_panel_ranges",
@@ -299,25 +301,26 @@ def _mesh_stream(state0: PanelState, A: jax.Array, *, panel: int, mesh, axis: st
             ctx = ops.bind_shard(ctx, w)
         st = dataclasses.replace(state, ctx=ctx, offset=(w * shard_n).astype(jnp.int32))
         st = scan_chunk(st, A_shard, panel)  # local scan; collectives below
-        ctx = st.ctx
-        if ops.collective_ctx is not None:
-            ctx = ops.collective_ctx(ctx, axis)
-        st = dataclasses.replace(
-            st,
-            C=jax.lax.psum(st.C, axis),
-            # symmetric streams carry the (0, n_pad) placeholder — nothing to reduce
-            R=jax.lax.psum(st.R, axis) if st.R.size else st.R,
-            M=jax.lax.psum(st.M, axis),
-            offset=jnp.asarray(n, jnp.int32),
-            ctx=ctx,
-            # telemetry reduces with the same disjoint-write algebra as C/R/M
-            tel=st.tel.collective(axis) if st.tel is not None else None,
-            quarantined=(
-                jax.lax.psum(st.quarantined, axis)
-                if st.quarantined is not None
-                else None
-            ),
-        )
+        with jax.named_scope(SCOPE_PSUM):
+            ctx = st.ctx
+            if ops.collective_ctx is not None:
+                ctx = ops.collective_ctx(ctx, axis)
+            st = dataclasses.replace(
+                st,
+                C=jax.lax.psum(st.C, axis),
+                # symmetric streams carry the (0, n_pad) placeholder — nothing to reduce
+                R=jax.lax.psum(st.R, axis) if st.R.size else st.R,
+                M=jax.lax.psum(st.M, axis),
+                offset=jnp.asarray(n, jnp.int32),
+                ctx=ctx,
+                # telemetry reduces with the same disjoint-write algebra as C/R/M
+                tel=st.tel.collective(axis) if st.tel is not None else None,
+                quarantined=(
+                    jax.lax.psum(st.quarantined, axis)
+                    if st.quarantined is not None
+                    else None
+                ),
+            )
         return ops.merge_state(st) if ops.merge_state is not None else st
 
     specs = jax.tree_util.tree_map(lambda _: P(), state0)

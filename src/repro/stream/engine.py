@@ -76,7 +76,25 @@ __all__ = [
     "truncated_R",
     "with_quarantine",
     "zero_nonfinite_panels",
+    "SCOPES",
 ]
+
+# Device scopes (``jax.named_scope``): every stage of a panel update carries
+# one of these names in the ``op_name`` metadata of its HLO, so a device
+# trace splits the scan's time by stage (docs/observability.md). A scope is
+# metadata only: the optimized program is otherwise the same without it.
+SCOPE_SKETCH = "stream.sketch"  # S_C applied to the chunk or panel, and its operand read
+SCOPE_CHUNK_FOLD = "stream.chunk_fold"  # ops.chunk_fold
+SCOPE_MFOLD = "stream.mfold"  # M += (S_C A_L) S_R[:, off:off+L]ᵀ
+SCOPE_ADMIT = "stream.admit"  # scoring and admission: sketch_panel, update_c, fused_step
+SCOPE_ROWS = "stream.rows"  # update_r / r_block
+SCOPE_PANEL_KERNEL = "stream.panel_kernel"  # Route B launch + slot-table bookkeeping
+SCOPE_PSUM = "stream.psum"  # the mesh program's collectives
+SCOPE_SOLVE = "finalize.solve"  # the finalizers' core solve
+SCOPES = (
+    SCOPE_SKETCH, SCOPE_CHUNK_FOLD, SCOPE_MFOLD, SCOPE_ADMIT, SCOPE_ROWS,
+    SCOPE_PANEL_KERNEL, SCOPE_PSUM, SCOPE_SOLVE,
+)
 
 
 def copy_selected_columns(col_idx, C, A_L, off):
@@ -359,7 +377,8 @@ def panel_update(state: PanelState, A_L: jax.Array) -> PanelState:
     if ops.panel_kernel is not None:
         # Route B: one fused Pallas launch replaces the sketch, the M fold
         # and update_c when the hook accepts (None = trace-time decline).
-        fast = ops.panel_kernel(state.ctx, state.C, state.M, A_L, off)
+        with jax.named_scope(SCOPE_PANEL_KERNEL):
+            fast = ops.panel_kernel(state.ctx, state.C, state.M, A_L, off)
     if fast is not None:
         ctx, C, M, sc_a, scores = fast
     else:
@@ -367,22 +386,28 @@ def panel_update(state: PanelState, A_L: jax.Array) -> PanelState:
         if ops.sketch_panel is not None:
             # fused path: the application computes sc_a together with its
             # per-column scores (one pass; see kernels.panel_score on TPU)
-            ctx, sc_a, scores = ops.sketch_panel(state.ctx, A_L, off)
+            with jax.named_scope(SCOPE_ADMIT):
+                ctx, sc_a, scores = ops.sketch_panel(state.ctx, A_L, off)
         else:
-            ctx, sc_a, scores = state.ctx, S_C.apply(A_L), None
-        M = state.M + S_R.cols(off, L).apply_t(sc_a).astype(state.M.dtype)
+            with jax.named_scope(SCOPE_SKETCH):
+                ctx, sc_a, scores = state.ctx, S_C.apply(A_L), None
+        with jax.named_scope(SCOPE_MFOLD):
+            M = state.M + S_R.cols(off, L).apply_t(sc_a).astype(state.M.dtype)
 
-        if scores is None:
-            ctx, C = ops.update_c(ctx, state.C, A_L, sc_a, off)
-        else:
-            ctx, C = ops.update_c(ctx, state.C, A_L, sc_a, off, scores)
+        with jax.named_scope(SCOPE_ADMIT):
+            if scores is None:
+                ctx, C = ops.update_c(ctx, state.C, A_L, sc_a, off)
+            else:
+                ctx, C = ops.update_c(ctx, state.C, A_L, sc_a, off, scores)
     if ops.symmetric:
         R = state.R  # tied operand: R = Cᵀ is derived, nothing to accumulate
     elif ops.update_r is not None:
-        R = ops.update_r(ctx, state.R, A_L, off)
+        with jax.named_scope(SCOPE_ROWS):
+            R = ops.update_r(ctx, state.R, A_L, off)
     else:
-        r_blk = ops.r_block(ctx, A_L, off).astype(state.R.dtype)
-        R = jax.lax.dynamic_update_slice_in_dim(state.R, r_blk, off, axis=1)
+        with jax.named_scope(SCOPE_ROWS):
+            r_blk = ops.r_block(ctx, A_L, off).astype(state.R.dtype)
+            R = jax.lax.dynamic_update_slice_in_dim(state.R, r_blk, off, axis=1)
 
     # Telemetry fold runs last — it observes the panel's outcome (pre/post
     # ctx) and only writes the diagnostics frame, never the factors.
@@ -449,10 +474,12 @@ def _fused_scan(
     ops = state.ops
     start = state.offset
     S_C, S_R = ops.core_sketches(state.ctx)
-    sca = S_C.apply(window)  # (s_c, width) — all panel sketches, one pass
-    ctx, C, R = ops.chunk_fold(
-        state.ctx, state.C, state.R, block, bcol0, start, num_panels * panel
-    )
+    with jax.named_scope(SCOPE_SKETCH):
+        sca = S_C.apply(window)  # (s_c, width) — all panel sketches, one pass
+    with jax.named_scope(SCOPE_CHUNK_FOLD):
+        ctx, C, R = ops.chunk_fold(
+            state.ctx, state.C, state.R, block, bcol0, start, num_panels * panel
+        )
     has_tel = ops.telemetry is not None and state.tel is not None
     # telemetry hooks read A_L's static shape only (see PanelOps.telemetry)
     placeholder = jnp.zeros((0, panel), block.dtype)
@@ -460,13 +487,16 @@ def _fused_scan(
     def body(carry, t):
         ctx, C, M, tel = carry
         off = start + t * panel
-        sc_a = jax.lax.dynamic_slice_in_dim(sca, t * panel, panel, axis=1)
-        M = M + S_R.cols(off, panel).apply_t(sc_a).astype(M.dtype)
+        with jax.named_scope(SCOPE_SKETCH):
+            sc_a = jax.lax.dynamic_slice_in_dim(sca, t * panel, panel, axis=1)
+        with jax.named_scope(SCOPE_MFOLD):
+            M = M + S_R.cols(off, panel).apply_t(sc_a).astype(M.dtype)
         ctx_pre, scores = ctx, None
         if ops.fused_step is not None:
-            ctx, C, scores = ops.fused_step(
-                ctx, C, block, bcol0 + t * panel, sc_a, off
-            )
+            with jax.named_scope(SCOPE_ADMIT):
+                ctx, C, scores = ops.fused_step(
+                    ctx, C, block, bcol0 + t * panel, sc_a, off
+                )
         if has_tel:
             tel = ops.telemetry(tel, ctx_pre, ctx, placeholder, sc_a, scores, off)
         return (ctx, C, M, tel), None
@@ -518,7 +548,8 @@ def scan_chunk(
         return _fused_scan(state, A_chunk, 0, A_chunk, num_panels, panel)
 
     def body(st, t):
-        A_L = jax.lax.dynamic_slice_in_dim(A_chunk, t * panel, panel, axis=1)
+        with jax.named_scope(SCOPE_SKETCH):  # the panel is the sketch's operand
+            A_L = jax.lax.dynamic_slice_in_dim(A_chunk, t * panel, panel, axis=1)
         return panel_update(st, A_L), None
 
     state, _ = jax.lax.scan(body, state, jnp.arange(num_panels, dtype=jnp.int32))
@@ -556,13 +587,15 @@ def scan_panels(
         )
 
     if fused and _fused_route_ok(state):
-        window = jax.lax.dynamic_slice_in_dim(
-            A, state.offset, num_panels * panel, axis=1
-        )
+        with jax.named_scope(SCOPE_SKETCH):  # the chunk sketch's operand
+            window = jax.lax.dynamic_slice_in_dim(
+                A, state.offset, num_panels * panel, axis=1
+            )
         return _fused_scan(state, A, state.offset, window, num_panels, panel)
 
     def body(st, off):
-        A_L = jax.lax.dynamic_slice_in_dim(A, off, panel, axis=1)
+        with jax.named_scope(SCOPE_SKETCH):  # the panel is the sketch's operand
+            A_L = jax.lax.dynamic_slice_in_dim(A, off, panel, axis=1)
         return panel_update(st, A_L), None
 
     state, _ = jax.lax.scan(body, state, offs)

@@ -10,11 +10,17 @@ The load-bearing guarantees, in test order:
 * the in-stream test sketch ``Ψ = A Ω_test`` is exact (single-host and
   simulated-sharded), and the estimator lands inside a 2× band of the true
   relative error on the three synthetic stream families;
-* worker telemetry frames merge by summation to the single-stream frame.
+* worker telemetry frames merge by summation to the single-stream frame;
+* the engine's device scopes name every stage in the compiled program's
+  ``op_name`` metadata and change nothing else, and its host spans reach a
+  live profiler whether or not the registry is enabled.
 """
 
+import contextlib
+import glob
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -36,7 +42,6 @@ from repro.obs import (
     MetricsRegistry,
     default_registry,
     estimate_rel_error,
-    render_timeline,
     set_registry,
     span,
     telemetry_summary,
@@ -53,7 +58,8 @@ from repro.stream import (
     simulate_sharded_stream,
     stream_panels,
 )
-from repro.stream.engine import scan_chunk
+from repro.stream import engine
+from repro.stream.engine import SCOPES, scan_chunk
 
 M, N, PANEL = 160, 128, 32
 CI = jnp.asarray([3, 17, 40, 63, 77, 90, 101, 120], jnp.int32)
@@ -275,6 +281,168 @@ def test_estimator_requires_telemetry():
         estimate_rel_error(st)
 
 
+# ------------------------------------------------------------ device scopes
+
+# debug information of a compiled program: op-name metadata and the
+# source-location tables it points into
+_METADATA = re.compile(r',? metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}')
+_FRAMES = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*", re.M)
+
+
+def _scopes_in(hlo: str) -> set:
+    """The scope names (``stream.*``, ``finalize.*``) in a program's op names."""
+    paths = " ".join(re.findall(r'op_name="([^"]*)"', hlo))
+    return set(re.findall(r"(?:stream|finalize)\.[a-z_]+", paths))
+
+
+def _stripped(hlo: str) -> str:
+    return _FRAMES.sub("", _METADATA.sub("", hlo))
+
+
+def _gauss_state():
+    return adaptive_cur_init(
+        jax.random.key(51), M, N, 8, jnp.arange(8, dtype=jnp.int32),
+        s_c=64, s_r=64, sketch="gaussian", panel=PANEL, panel_cap=2,
+    )
+
+
+def _fixed_rows_state():
+    return adaptive_cur_init(
+        jax.random.key(4), M, N, 8, RI, sketch="countsketch", panel=PANEL, panel_cap=2
+    )
+
+
+@contextlib.contextmanager
+def _kernel_route(on: bool):
+    from repro.kernels import ops as kops
+
+    kops._FORCE_KERNEL_ROUTE = on
+    try:
+        yield
+    finally:
+        kops._FORCE_KERNEL_ROUTE = False
+
+
+# route -> (state, fused, kernel route forced, scopes its stream program holds)
+ROUTES = {
+    "fused": (_fixed_rows_state, True, False,
+              {"stream.sketch", "stream.chunk_fold", "stream.mfold", "stream.admit"}),
+    "per_panel": (lambda: _fixed_state(False), False, False,
+                  {"stream.sketch", "stream.mfold", "stream.admit", "stream.rows"}),
+    "kernel": (_gauss_state, True, True, {"stream.sketch", "stream.panel_kernel", "stream.rows"}),
+}
+
+
+def _stream_hlo(route: str, scan=None) -> str:
+    make, fused, kernel, _ = ROUTES[route]
+    A = jax.ShapeDtypeStruct((M, N), jnp.float32)
+    with _kernel_route(kernel):
+        fn = scan or engine._scan_stream_panels
+        return fn.lower(make(), A, num_panels=N // PANEL, panel=PANEL, fused=fused).compile().as_text()
+
+
+@pytest.mark.parametrize("route", sorted(ROUTES))
+def test_stream_scopes_in_compiled_hlo(route):
+    """Each stage of the route's scan body carries its scope in the compiled
+    program's op names: the fused CountSketch scan, the per-panel body, and
+    Route B's kernel (forced on the CPU)."""
+    assert _scopes_in(_stream_hlo(route)) == ROUTES[route][3]
+
+
+@pytest.mark.parametrize("route", sorted(ROUTES))
+def test_scopes_change_only_metadata(route, monkeypatch):
+    """The optimized stream program with its metadata stripped is the text
+    it compiles to when every scope is a no-op."""
+    scoped = _stream_hlo(route)
+
+    def scan_panels(state, A, num_panels, panel, *, fused=True):  # fresh: no trace cache
+        return engine.scan_panels(state, A, num_panels, panel, fused=fused)
+
+    bare_jit = jax.jit(scan_panels, static_argnames=("num_panels", "panel", "fused"),
+                       donate_argnums=(0,))
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    bare = _stream_hlo(route, bare_jit)
+    assert not _scopes_in(bare) and _scopes_in(scoped)
+    assert _stripped(scoped) == _stripped(bare)
+
+
+@pytest.mark.parametrize(
+    "make,finalize",
+    [
+        (lambda: _fixed_state(False), streaming_cur_finalize),
+        (_fixed_rows_state, adaptive_cur_finalize),
+        (lambda: streaming_spsd_init(jax.random.key(9), N, CI, s=48, panel=PANEL),
+         streaming_spsd_finalize),
+        (lambda: adaptive_spsd_init(jax.random.key(10), N, 8, s=48, panel=PANEL),
+         adaptive_spsd_finalize),
+    ],
+    ids=["streaming_cur", "adaptive_cur", "streaming_spsd", "adaptive_spsd"],
+)
+def test_finalize_solve_scope(make, finalize):
+    """Each finalizer's core solve runs in ``finalize.solve``."""
+    hlo = finalize.__wrapped__.lower(make()).compile().as_text()
+    assert _scopes_in(hlo) == {"finalize.solve"}
+
+
+_MESH_SCRIPT = """
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh
+from repro.stream.adaptive import adaptive_cur_init
+from repro.stream.distributed import _mesh_stream
+m, n, panel = 64, 256, 32
+st = adaptive_cur_init(jax.random.key(0), m, n, 8, jnp.arange(8, dtype=jnp.int32),
+                       sketch="countsketch", s_c=32, s_r=32, panel=panel, panel_cap=2)
+st = st.__class__(**{**st.__dict__, "ctx": st.ops.prep_shard(st.ctx, 4)})
+mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+A = jax.ShapeDtypeStruct((m, n), jnp.float32)
+print(_mesh_stream.lower(st, A, panel=panel, mesh=mesh, axis="data").compile().as_text())
+"""
+
+
+def test_mesh_stream_scopes_in_compiled_hlo():
+    """The mesh program (4 virtual devices) holds the scan's scopes and puts
+    its collectives in ``stream.psum``."""
+    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], capture_output=True,
+                          text=True, env=env, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    hlo = proc.stdout
+    assert _scopes_in(hlo) == {"stream.sketch", "stream.chunk_fold", "stream.mfold",
+                               "stream.admit", "stream.psum"}
+    psum_ops = [line for line in hlo.splitlines() if "all-reduce(" in line]
+    assert psum_ops and all("stream.psum" in line for line in psum_ops)
+
+
+def test_scope_vocabulary_is_covered():
+    """Every scope of the vocabulary is one that a test above finds in a
+    compiled program, and each is ``<layer>.<stage>``."""
+    tested = set().union(*(route[3] for route in ROUTES.values()))
+    assert set(SCOPES) == tested | {"stream.psum", "finalize.solve"}
+    assert all(re.fullmatch(r"(?:stream|finalize)\.[a-z_]+", s) for s in SCOPES)
+
+
+def test_spans_reach_profiler_with_registry_disabled(tmp_path):
+    """With the registry disabled and a profiler session live, the engine's
+    host spans are in the trace and no span is recorded."""
+    from jax.profiler import ProfileData
+
+    assert default_registry().enabled is False
+    A = _A()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        st = stream_panels(_fixed_state(False), A, PANEL)
+        jax.block_until_ready(streaming_cur_finalize(st))
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(pb).planes
+             for line in plane.lines for e in line.events}
+    assert {"stream/streaming_cur/init", "stream/streaming_cur/scan",
+            "stream/streaming_cur/finalize"} <= names
+    assert not default_registry().spans
+
+
 # ------------------------------------------------------------- host registry
 
 
@@ -291,15 +459,14 @@ def test_registry_instruments_and_jsonl(tmp_path):
         with span("inner", reg):
             pass
     assert [s.name for s in reg.spans] == ["inner", "outer"]  # closed order
-    assert reg.spans[0].depth == 1 and reg.spans[1].depth == 0
+    assert reg.spans[0].parent == "outer" and reg.spans[1].parent is None
     path = tmp_path / "metrics.jsonl"
     reg.dump_jsonl(path)
     recs = [json.loads(line) for line in path.read_text().splitlines()]
     assert {"counter", "gauge", "histogram", "span"} <= {r["type"] for r in recs}
     assert next(r for r in recs if r["name"] == "a/count")["value"] == 5
-    tl = render_timeline(reg)
-    assert "outer" in tl and "inner" in tl
-    assert render_timeline(MetricsRegistry()) == "(no spans recorded)"
+    spans = {r["name"]: r for r in recs if r["type"] == "span"}
+    assert spans["inner"]["parent"] == "outer" and spans["outer"]["parent"] is None
 
 
 def test_disabled_registry_is_inert():
@@ -318,9 +485,10 @@ def test_default_registry_swap_and_engine_spans():
     assert default_registry().enabled is False
     prev = set_registry(MetricsRegistry())
     try:
-        stream_panels(_fixed_state(False), _A(), PANEL)
+        streaming_cur_finalize(stream_panels(_fixed_state(False), _A(), PANEL))
         names = [s.name for s in default_registry().spans]
-        assert "stream/streaming_cur/scan" in names
+        assert names == ["stream/streaming_cur/init", "stream/streaming_cur/scan",
+                         "stream/streaming_cur/finalize"]
     finally:
         set_registry(prev)
 
