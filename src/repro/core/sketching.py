@@ -38,6 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import ops as kernel_ops
+from ..obs.metrics import default_registry
+
 __all__ = [
     "GaussianSketch",
     "SRHTSketch",
@@ -186,9 +189,17 @@ _register(SRHTSketch, ("signs", "row_idx"), ("m", "m_pad"))
 class CountSketch:
     """One ±1 entry per column, position uniform (Clarkson & Woodruff 2013).
 
-    ``apply`` is a signed segment-sum — the JAX-native statement of the
-    O(nnz(A)) input-sparsity algorithm. The TPU-tiled variant lives in
-    ``repro.kernels.countsketch``.
+    ``apply`` is a signed segment-sum — the O(nnz(A)) input-sparsity
+    algorithm. On a TPU a 2-D operand takes the row-accumulating Pallas
+    kernel (``repro.kernels.countsketch``), which XLA's serial scatter loop
+    would otherwise run; other backends, operands of other rank, and
+    operands laid out over several devices outside ``shard_map`` (which XLA
+    must partition, and cannot partition a kernel) take
+    ``jax.ops.segment_sum``. The kernel adds the signed rows in ascending
+    order in float32, as ``segment_sum`` does for a float32 result, so the
+    two agree bit for bit there. The route taken is counted at trace time
+    under ``sketch.countsketch.route.kernel`` /
+    ``sketch.countsketch.route.segment_sum``.
     """
 
     hashes: jax.Array  # (m,) int32 in [0, s)
@@ -208,6 +219,13 @@ class CountSketch:
 
     def apply(self, A: jax.Array) -> jax.Array:
         m = A.shape[0]
+        reg = default_registry()
+        if (A.ndim == 2 and kernel_ops.kernel_route_enabled()
+                and kernel_ops.kernel_partitionable(A)):
+            reg.inc("sketch.countsketch.route.kernel")
+            out = kernel_ops.countsketch_apply(self.hashes[:m], self.signs[:m], A, self.s)
+            return out.astype(jnp.result_type(A.dtype, self.signs.dtype))
+        reg.inc("sketch.countsketch.route.segment_sum")
         signed = A * _bcast_vec(self.signs[:m], A.ndim)
         return jax.ops.segment_sum(signed, self.hashes[:m], num_segments=self.s)
 

@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the paper's compute hot spots.
 
 twoside_sketch — fused S_C·A·S_Rᵀ (Algorithm 1/3 inner sketch)
-countsketch    — TPU-adapted input-sparsity CountSketch (one-hot MXU matmul)
+countsketch    — input-sparsity CountSketch: each signed row of A added into
+                 its bucket row of a VMEM accumulator (CountSketch.apply on TPU)
 panel_score    — fused streaming panel scoring: S_C·A_L + column energies +
                  admitted-basis residuals in one VMEM pass (adaptive CUR)
 panel_update   — fused panel-update megakernel: panel_score's triple plus

@@ -2,11 +2,12 @@
 
 On non-TPU backends ``interpret=True`` executes the kernel bodies in
 Python for correctness; on TPU the same code lowers to Mosaic. The
-wrappers pad every dim to its block multiple with zeros (mathematically a
-no-op for every kernel: zero rows/cols contribute nothing) and slice the
-result back.
+matmul wrappers pad every dim to its block multiple with zeros
+(mathematically a no-op for every kernel: zero rows/cols contribute
+nothing) and slice the result back; ``countsketch_apply`` clips its edge
+blocks instead, so its operand is never copied.
 
-All four kernels share one block scheme (:data:`LANE`/:data:`SUBLANE`
+The matmul kernels share one block scheme (:data:`LANE`/:data:`SUBLANE`
 tile floor, :func:`pad_dims` zero-padding, :func:`interpret_default`
 backend dispatch), so a re-tiling decision is made once here rather than
 per kernel.
@@ -18,6 +19,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from .countsketch import countsketch_kernel
 from .panel_score import panel_score_kernel
@@ -102,6 +104,18 @@ def twoside_sketch(
     return out[:s_c, :s_r]
 
 
+def kernel_partitionable(x: jax.Array) -> bool:
+    """May a Pallas kernel take ``x`` where it is traced?
+
+    XLA cannot partition a Mosaic kernel: a kernel call fails to lower in a
+    program laid out over several devices by XLA (a jit over a mesh), and
+    lowers on one device or inside ``shard_map``, where every mesh axis is
+    manual. ``x``'s type carries the mesh it is laid out over.
+    """
+    mesh = jax.typeof(x).sharding.mesh
+    return mesh.size <= 1 or all(t == AxisType.Manual for t in mesh.axis_types)
+
+
 @partial(jax.jit, static_argnames=("s", "block_m", "block_n", "interpret"))
 def countsketch_apply(
     hashes: jax.Array,
@@ -109,26 +123,20 @@ def countsketch_apply(
     a: jax.Array,
     s: int,
     *,
-    block_m: int = 256,
-    block_n: int = 256,
+    block_m: int = 1024,
+    block_n: int = 2048,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """S·A for a CountSketch given (hash, sign) vectors. Returns (s, n) fp32."""
+    """S·A for a CountSketch given (hash, sign) vectors. Returns (s, n) fp32.
+
+    ``a`` is read in place: no dim is padded (the kernel clips its edge
+    blocks), so the stream's 4 GiB chunk is never copied.
+    """
     interpret = interpret_default() if interpret is None else interpret
-    m, n = a.shape
-    s_pad = s + ((-s) % LANE)
-    (ap,) = pad_dims((a, (block_m, block_n)))
-    # padded rows must not pollute bucket 0: send them to the padding bucket
-    (hp,) = pad_dims((hashes, (block_m,)))
-    if hp.shape[0] != m:
-        filler = jnp.full((hp.shape[0] - m,), s_pad - 1 if s_pad > s else s - 1, hp.dtype)
-        hp = hp.at[m:].set(filler)
-    (sgp,) = pad_dims((signs, (block_m,)))  # zero signs ⇒ padded rows contribute 0
-    out = countsketch_kernel(
-        hp[None, :], sgp[None, :], ap, s_pad,
+    return countsketch_kernel(
+        hashes.astype(jnp.int32), signs.astype(jnp.float32), a, s,
         block_m=block_m, block_n=block_n, interpret=interpret,
     )
-    return out[:s, : n]
 
 
 @partial(jax.jit, static_argnames=("block_m", "block_l", "interpret"))
@@ -250,6 +258,7 @@ __all__ = [
     "pad_dims",
     "interpret_default",
     "kernel_route_enabled",
+    "kernel_partitionable",
     "twoside_sketch",
     "countsketch_apply",
     "panel_score",

@@ -68,6 +68,224 @@ def test_countsketch_padding_no_bucket_pollution():
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
 
+def _segment_sum_apply(S, A):
+    """``CountSketch.apply`` as it runs off the TPU: the signed segment sum."""
+    from repro.kernels import ops as kops
+
+    assert not kops.kernel_route_enabled()
+    return S.apply(A)
+
+
+def _assert_same_sums(out, ref):
+    """Bitwise where the kernel adds in segment_sum's order (float32 sums of
+    exactly converted rows); otherwise within 1e-6 of the largest entry."""
+    if out.dtype == ref.dtype == jnp.float32:
+        np.testing.assert_array_equal(out, ref)
+    else:
+        scale = float(jnp.max(jnp.abs(ref.astype(jnp.float32)))) + 1e-30
+        diff = jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))
+        assert float(jnp.max(diff)) <= 1e-6 * scale
+
+
+# (s, m, n, block_m, block_n): several row and column blocks, ragged edges
+CS_KERNEL_CASES = [
+    (64, 300, 200, 128, 128),  # s < 128, m and n ragged against the blocks
+    (130, 1000, 300, 256, 128),  # s not a multiple of 128 (nor of 8)
+    (256, 512, 384, 128, 256),  # aligned: no edge block
+    (7, 100, 50, 2048, 1024),  # one block larger than the operand
+]
+
+
+@pytest.mark.parametrize("case", CS_KERNEL_CASES, ids=lambda c: "s{}_m{}_n{}_bm{}_bn{}".format(*c))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_countsketch_kernel_matches_segment_sum(case, dtype):
+    from repro.core.sketching import CountSketch
+
+    s, m, n, bm, bn = case
+    ks = jax.random.split(jax.random.key(sum(case)), 2)
+    S = CountSketch.draw(ks[0], s, m)
+    A = jax.random.normal(ks[1], (m, n), jnp.float32).astype(dtype)
+    out = countsketch_apply(S.hashes, S.signs, A, s, block_m=bm, block_n=bn, interpret=True)
+    assert out.shape == (s, n) and out.dtype == jnp.float32
+    _assert_same_sums(out, _segment_sum_apply(S, A))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_countsketch_kernel_all_rows_one_bucket(dtype):
+    """Every row in one bucket: the most collisions, one long running sum."""
+    from repro.core.sketching import CountSketch
+
+    s, m, n = 40, 700, 260
+    ks = jax.random.split(jax.random.key(3), 2)
+    S = CountSketch.draw(ks[0], s, m)
+    S = CountSketch(hashes=jnp.full((m,), 17, jnp.int32), signs=S.signs, s=s)
+    A = jax.random.normal(ks[1], (m, n), jnp.float32).astype(dtype)
+    out = countsketch_apply(S.hashes, S.signs, A, s, block_m=256, block_n=128, interpret=True)
+    ref = _segment_sum_apply(S, A)
+    _assert_same_sums(out, ref)
+    assert not bool(jnp.any(out[:17])) and not bool(jnp.any(out[18:]))
+
+
+def test_countsketch_kernel_pad_cols_rows_are_inert():
+    """``pad_cols`` rows (hash 0, sign 0) add nothing — also where the
+    operand's padded rows hold data, and to bucket 0 in particular."""
+    from repro.core.sketching import CountSketch
+
+    s, m, total, n = 48, 300, 520, 200
+    ks = jax.random.split(jax.random.key(4), 2)
+    S = CountSketch.draw(ks[0], s, m).pad_cols(total)
+    A = jax.random.normal(ks[1], (total, n), jnp.float32) + 3.0
+    out = countsketch_apply(S.hashes, S.signs, A, s, block_m=128, block_n=128, interpret=True)
+    _assert_same_sums(out, _segment_sum_apply(S, A))
+    unpadded = countsketch_apply(S.hashes[:m], S.signs[:m], A[:m], s, interpret=True)
+    np.testing.assert_array_equal(out, unpadded)
+
+
+@pytest.fixture
+def countsketch_kernel_route(monkeypatch):
+    """``CountSketch.apply`` takes the Pallas kernel (interpret mode) as on a
+    TPU; every program traces anew so the route reaches the engine's jits."""
+    from repro.kernels import ops as kops
+    from repro.obs.metrics import MetricsRegistry, set_registry
+
+    monkeypatch.setattr(kops, "kernel_route_enabled", lambda: True)
+    jax.clear_caches()
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    yield reg
+    set_registry(prev)
+    jax.clear_caches()
+
+
+def test_countsketch_apply_routes_to_kernel(countsketch_kernel_route):
+    from repro.core.sketching import CountSketch
+
+    reg = countsketch_kernel_route
+    S = CountSketch.draw(jax.random.key(5), 96, 400)
+    A = jax.random.normal(jax.random.key(6), (400, 150))
+    out = S.apply(A)
+    assert reg.counters == {"sketch.countsketch.route.kernel": 1}
+    np.testing.assert_array_equal(out, jax.ops.segment_sum(A * S.signs[:, None], S.hashes, num_segments=96))
+    # rank 3 stays on segment_sum; apply_t goes through apply
+    S.apply(A.reshape(400, 10, 15))
+    S.apply_t(A.T)
+    assert reg.counters == {"sketch.countsketch.route.kernel": 2,
+                            "sketch.countsketch.route.segment_sum": 1}
+
+
+def test_countsketch_apply_off_tpu_is_segment_sum():
+    from repro.core.sketching import CountSketch
+    from repro.obs.metrics import MetricsRegistry, set_registry
+
+    prev = set_registry(MetricsRegistry())
+    try:
+        S = CountSketch.draw(jax.random.key(7), 32, 64)
+        S.apply(jnp.ones((64, 8)))
+        from repro.obs.metrics import default_registry
+
+        assert default_registry().counters == {"sketch.countsketch.route.segment_sum": 1}
+    finally:
+        set_registry(prev)
+
+
+_MESH_ROUTE_SCRIPT = r"""
+import json
+import jax, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core.sketching import CountSketch
+from repro.kernels import ops as kops
+from repro.obs.metrics import MetricsRegistry, set_registry
+
+kops.kernel_route_enabled = lambda: True
+reg = MetricsRegistry()
+set_registry(reg)
+mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+S = CountSketch.draw(jax.random.key(0), 96, 700)
+A = jax.random.normal(jax.random.key(1), (700, 512))
+ref = np.asarray(jax.ops.segment_sum(A * S.signs[:, None], S.hashes, num_segments=96))
+out = {}
+for name, spec in (("replicated", P()), ("columns", P(None, "data"))):
+    got = jax.jit(lambda S, a: S.apply(a))(jax.device_put(S, NamedSharding(mesh, P())),
+                                           jax.device_put(A, NamedSharding(mesh, spec)))
+    out[name] = bool((np.asarray(got) == ref).all())
+out["on_mesh"] = dict(reg.counters)
+reg.counters.clear()
+sm = jax.jit(jax.shard_map(S.apply, mesh=mesh, in_specs=P(None, "data"), out_specs=P(None, "data")))
+out["shard_map"] = bool((np.asarray(sm(jax.device_put(A, NamedSharding(mesh, P(None, "data"))))) == ref).all())
+out["in_shard_map"] = dict(reg.counters)
+print(json.dumps(out))
+"""
+
+
+def test_countsketch_route_on_a_mesh():
+    """On 4 devices (virtual CPUs, kernel route forced): an operand laid out
+    over the mesh by a jit takes ``segment_sum`` (XLA cannot partition a
+    kernel); inside ``shard_map`` each device runs the kernel on its columns
+    (interpret mode) — both equal to the unsharded segment sum."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
+    proc = subprocess.run([sys.executable, "-c", _MESH_ROUTE_SCRIPT], capture_output=True,
+                          text=True, env=env, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["replicated"] and out["columns"] and out["shard_map"], out
+    assert out["on_mesh"] == {"sketch.countsketch.route.segment_sum": 2}
+    assert out["in_shard_map"] == {"sketch.countsketch.route.kernel": 1}
+
+
+def test_engine_on_countsketch_kernel_matches_per_panel_oracle(countsketch_kernel_route):
+    """Fused scan (one chunk sketch, per-panel M fold) and the per-panel
+    oracle, both on the kernel route, give the C, R and M of the
+    segment_sum route: adaptive CUR (admission in-stream) and SPSD."""
+    from repro.data.synthetic import spiked_decay_matrix
+    from repro.kernels import ops as kops
+    from repro.spsd import streaming_spsd_init
+    from repro.stream.adaptive import adaptive_cur_init
+    from repro.stream.engine import stream_panels
+
+    reg = countsketch_kernel_route
+    m, n, panel = 200, 250, 40
+    B, _ = spiked_decay_matrix(jax.random.key(30), m, n)
+    K = B[:, :m] @ B[:, :m].T
+
+    def cur():
+        return adaptive_cur_init(
+            jax.random.key(31), m, n, 10, jnp.arange(12, dtype=jnp.int32),
+            sketch="countsketch", panel=panel, panel_cap=2,
+        )
+
+    def spsd():
+        return streaming_spsd_init(
+            jax.random.key(32), m, jnp.arange(0, m, 20, dtype=jnp.int32), s=64, panel=panel
+        )
+
+    results = {}
+    for name, init, mat in (("cur", cur, B), ("spsd", spsd, K)):
+        for jit in ("per-panel", "scan"):
+            results[name, jit] = stream_panels(init(), mat, panel, jit=jit)
+    assert reg.counters["sketch.countsketch.route.kernel"] > 0
+    assert "sketch.countsketch.route.segment_sum" not in reg.counters
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kops, "kernel_route_enabled", lambda: False)
+        jax.clear_caches()
+        for name, init, mat in (("cur", cur, B), ("spsd", spsd, K)):
+            results[name, "segment_sum"] = stream_panels(init(), mat, panel, jit="per-panel")
+    for name in ("cur", "spsd"):
+        ref = results[name, "per-panel"]
+        for got in (results[name, "scan"], results[name, "segment_sum"]):
+            np.testing.assert_array_equal(got.C, ref.C)
+            np.testing.assert_array_equal(got.R, ref.R)
+            np.testing.assert_array_equal(got.M, ref.M)
+            assert int(got.offset) == int(ref.offset)
+    np.testing.assert_array_equal(results["cur", "scan"].ctx.col_idx, results["cur", "per-panel"].ctx.col_idx)
+
+
 def test_twoside_block_shape_sweep():
     """Same result across BlockSpec tilings (grid decomposition invariance)."""
     s_c, m, n, s_r = 128, 512, 512, 128

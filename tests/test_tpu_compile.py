@@ -29,7 +29,7 @@ KERNELS = ["twoside_sketch", "countsketch_apply", "panel_score", "panel_update"]
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -43,9 +43,14 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _kernel_call(name, m, L, s_c, c, shape):
@@ -82,3 +87,62 @@ def test_kernel_compiles_for_v5e(one_chip, name, width):
     fn, args = _kernel_call(name, *width, shape)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# (m, n, s, dtype): the CountSketch kernel at the cells' shapes — the chunk
+# sketch of a 32768² operand at s_c = 3840 (CUR) and s = 2560 (SPSD), and
+# the per-panel M fold, 512 rows of sc_aᵀ into (s_r, s_c) = (3840, 3840);
+# and a bfloat16 fold, whose rows the kernel loads as whole packed tiles
+CS_CELL_SHAPES = [(32768, 32768, 3840, "float32"), (32768, 32768, 2560, "float32"),
+                  (512, 3840, 3840, "float32"), (512, 3840, 3840, "bfloat16")]
+
+
+@pytest.mark.parametrize("shape", CS_CELL_SHAPES, ids=lambda w: "m{}_n{}_s{}_{}".format(*w))
+def test_countsketch_compiles_for_v5e_at_cell_shapes(one_chip, shape):
+    m, n, s, dtype = shape
+
+    def arg(sh, dt):
+        return jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+
+    fn = partial(ops.countsketch_apply, s=s, interpret=False)
+    compiled = jax.jit(fn).lower(
+        arg((m,), jnp.int32), arg((m,), jnp.float32), arg((m, n), jnp.dtype(dtype))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the operand is read in place: no copy, pad or slice of it
+    short = {"float32": "f32", "bfloat16": "bf16"}[dtype]
+    made = [line for line in text.splitlines()
+            if f"= {short}[{m},{n}]{{" in line and "parameter(" not in line]
+    assert not made, made
+
+
+@pytest.mark.parametrize("where", ["mesh", "shard_map"])
+def test_countsketch_route_compiles_on_four_chips(topo, where, monkeypatch):
+    """``CountSketch.apply`` as on a TPU, with its operand laid out over a
+    2x2 mesh: a jit over the mesh (the four-chip finalizer's program) takes
+    ``segment_sum``, which XLA partitions, because XLA cannot partition a
+    Mosaic kernel; inside ``shard_map`` each chip runs the kernel."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.sketching import CountSketch
+
+    monkeypatch.setattr(ops, "kernel_route_enabled", lambda: True)
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    jax.clear_caches()
+    mesh = Mesh(topo.devices, ("data",))
+    m, n, s = 32768, 1024, 3840
+    whole = NamedSharding(mesh, P())
+    cols = NamedSharding(mesh, P(None, "data"))
+    sketch = CountSketch(hashes=jax.ShapeDtypeStruct((m,), jnp.int32, sharding=whole),
+                         signs=jax.ShapeDtypeStruct((m,), jnp.float32, sharding=whole), s=s)
+    if where == "mesh":
+        fn, a = (lambda S, a: S.apply(a)), jax.ShapeDtypeStruct((m, n), jnp.float32, sharding=whole)
+    else:
+        def fn(S, a):
+            return jax.shard_map(S.apply, mesh=mesh, in_specs=P(None, "data"),
+                                 out_specs=P(None, "data"))(a)
+
+        a = jax.ShapeDtypeStruct((m, n), jnp.float32, sharding=cols)
+    text = jax.jit(fn).lower(sketch, a).compile().as_text()
+    jax.clear_caches()
+    assert ("tpu_custom_call" in text) == (where == "shard_map")
