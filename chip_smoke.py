@@ -304,9 +304,10 @@ def stream_cur_phase(name, A, key, *, sketch: str, panel: int = PANEL, c: int = 
 
     on_tpu = jax.default_backend() == "tpu"
     kernel = _route_has_kernel(init, A, panel)
-    # Gaussian admission-only streams take the panel_update kernel on a TPU;
-    # CountSketch streams take the fused scan, which launches no kernel
-    check(kernel == (on_tpu and sketch == "gaussian"),
+    # on a TPU every route launches a kernel: Gaussian admission-only streams
+    # the panel_update kernel, CountSketch streams (the fused scan) the
+    # countsketch kernel
+    check(kernel == on_tpu,
           f"{name}: kernel launched={kernel} on {jax.default_backend()}")
     state, first_s, warm_s = _stream_twice(init, A, panel)
     res = jax.block_until_ready(adaptive_cur_finalize(state))

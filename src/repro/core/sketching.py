@@ -185,6 +185,14 @@ _register(SRHTSketch, ("signs", "row_idx"), ("m", "m_pad"))
 # ---------------------------------------------------------------------------
 
 
+def _takes_kernel(A: jax.Array) -> bool:
+    """Does a hashed sketch (CountSketch, OSNAP) apply to ``A`` through the
+    ``countsketch`` Pallas kernel? On a TPU (``kernel_route_enabled``), for a
+    2-D operand that XLA need not partition (``kernel_partitionable``)."""
+    return (A.ndim == 2 and kernel_ops.kernel_route_enabled()
+            and kernel_ops.kernel_partitionable(A))
+
+
 @dataclasses.dataclass(frozen=True)
 class CountSketch:
     """One ±1 entry per column, position uniform (Clarkson & Woodruff 2013).
@@ -220,8 +228,7 @@ class CountSketch:
     def apply(self, A: jax.Array) -> jax.Array:
         m = A.shape[0]
         reg = default_registry()
-        if (A.ndim == 2 and kernel_ops.kernel_route_enabled()
-                and kernel_ops.kernel_partitionable(A)):
+        if _takes_kernel(A):
             reg.inc("sketch.countsketch.route.kernel")
             out = kernel_ops.countsketch_apply(self.hashes[:m], self.signs[:m], A, self.s)
             return out.astype(jnp.result_type(A.dtype, self.signs.dtype))
@@ -266,9 +273,17 @@ _register(CountSketch, ("hashes", "signs"), ("s",))
 class OSNAPSketch:
     """``p`` ±1/√p entries per column (Nelson & Nguyen 2013).
 
-    Implemented as the mean of ``p`` independent CountSketches scaled by
+    Implemented as the sum of ``p`` independent CountSketches scaled by
     1/√p (the "with replacement" OSNAP variant standard in practice; the
     subspace-embedding property is preserved, validated in tests).
+
+    ``apply`` takes the route ``CountSketch.apply`` takes: on a TPU a 2-D
+    operand goes through the ``countsketch`` Pallas kernel once per hash
+    row, each term summed in ascending row order in float32 as
+    ``segment_sum`` sums it, and the ``p`` terms added in hash-row order;
+    elsewhere the ``p`` signed segment sums run vmapped. The route is
+    counted at trace time under ``sketch.osnap.route.kernel`` /
+    ``sketch.osnap.route.segment_sum``.
     """
 
     hashes: jax.Array  # (p, m)
@@ -289,6 +304,15 @@ class OSNAPSketch:
 
     def apply(self, A: jax.Array) -> jax.Array:
         m = A.shape[0]
+        reg = default_registry()
+        if _takes_kernel(A):
+            reg.inc("sketch.osnap.route.kernel")
+            out = kernel_ops.countsketch_apply(self.hashes[0, :m], self.signs[0, :m], A, self.s)
+            for j in range(1, self.p):
+                out = out + kernel_ops.countsketch_apply(
+                    self.hashes[j, :m], self.signs[j, :m], A, self.s)
+            return out.astype(jnp.result_type(A.dtype, self.signs.dtype))
+        reg.inc("sketch.osnap.route.segment_sum")
 
         def one(h, sg):
             signed = A * _bcast_vec(sg[:m], A.ndim)

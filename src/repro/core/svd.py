@@ -36,9 +36,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.spans import spanned
 from ..stream.engine import (
+    SCOPE_COLSKETCH,
+    SCOPE_SOLVE,
     PanelOps,
     PanelState,
+    fresh_pytree,
     padded_n,
     panel_update,
     stream_panels,
@@ -102,7 +106,7 @@ def _svd_update_c(sk: SPSVDSketches, C, A_L, sc_a, off):
     # C += A_L · Ω̃[cols]  with  Ω̃[cols] = Ω[:, cols]ᵀ · G_Cᵀ  (never materialized)
     L = A_L.shape[1]
     a_omega = sk.omega.cols(off, L).apply_t(A_L)  # A_L (m,L) × Ω[:,cols]ᵀ (L,c0) → (m, c0)
-    return sk, C + sk.g_c.apply_t(a_omega)  # (m, c)
+    return sk, C + sk.g_c.apply_t(a_omega).astype(C.dtype)  # (m, c)
 
 
 def _svd_r_block(sk: SPSVDSketches, A_L, off):
@@ -115,6 +119,7 @@ SP_SVD_OPS = PanelOps(
     core_sketches=_svd_core_sketches,
     update_c=_svd_update_c,
     r_block=_svd_r_block,
+    c_scope=SCOPE_COLSKETCH,
 )
 
 # Streaming state: the generic engine state with ctx = SPSVDSketches
@@ -122,6 +127,19 @@ SP_SVD_OPS = PanelOps(
 SPSVDState = PanelState
 
 
+def _check_sketch_shapes(sk: SPSVDSketches, m: int, n: int, sizes: dict) -> None:
+    """Raise unless each operator of ``sk`` is ``(rows, cols)`` as ``sizes``
+    and ``(m, n)`` ask."""
+    want = {"psi": (sizes["r0"], m), "g_r": (sizes["r"], sizes["r0"]),
+            "omega": (sizes["c0"], n), "g_c": (sizes["c"], sizes["c0"]),
+            "s_c": (sizes["s_c"], m), "s_r": (sizes["s_r"], n)}
+    for name, shape in want.items():
+        op = getattr(sk, name)
+        if (op.s, op.m) != shape:
+            raise ValueError(f"sketches.{name} is {(op.s, op.m)}, the sizes ask for {shape}")
+
+
+@spanned("stream/sp_svd/init")
 def spsvd_engine_init(
     key,
     m: int,
@@ -131,6 +149,7 @@ def spsvd_engine_init(
     dtype=jnp.float32,
     osnap_p: int = 2,
     panel: Optional[int] = None,
+    sketches: Optional[SPSVDSketches] = None,
 ) -> SPSVDState:
     """Engine-level Algorithm 3 state constructor (explicit ``sizes``).
 
@@ -146,18 +165,28 @@ def spsvd_engine_init(
     themselves are drawn over ``n`` — padding never consumes randomness, so
     results are identical across panel choices). vmap-compatible: all draw
     paths use traced-key-safe jax.random primitives.
+
+    ``sketches`` replaces the six drawn operators with the caller's (shapes
+    checked against ``sizes``; ``key`` and ``osnap_p`` are then unused), so
+    a caller can hand the engine a draw that it rebuilds elsewhere. They are
+    copied, as the scan path donates the state it consumes.
     """
     c, r, c0, r0, s_c, s_r = (sizes[x] for x in ("c", "r", "c0", "r0", "s_c", "s_r"))
     n_pad = padded_n(n, panel) if panel else n
-    keys = jax.random.split(key, 6)
-    sk = SPSVDSketches(
-        psi=OSNAPSketch.draw(keys[0], r0, m, p=osnap_p, dtype=dtype),
-        g_r=GaussianSketch.draw(keys[1], r, r0, dtype=dtype),
-        omega=OSNAPSketch.draw(keys[2], c0, n, p=osnap_p, dtype=dtype).pad_cols(n_pad),
-        g_c=GaussianSketch.draw(keys[3], c, c0, dtype=dtype),
-        s_c=OSNAPSketch.draw(keys[4], s_c, m, p=osnap_p, dtype=dtype),
-        s_r=OSNAPSketch.draw(keys[5], s_r, n, p=osnap_p, dtype=dtype).pad_cols(n_pad),
-    )
+    if sketches is None:
+        keys = jax.random.split(key, 6)
+        sk = SPSVDSketches(
+            psi=OSNAPSketch.draw(keys[0], r0, m, p=osnap_p, dtype=dtype),
+            g_r=GaussianSketch.draw(keys[1], r, r0, dtype=dtype),
+            omega=OSNAPSketch.draw(keys[2], c0, n, p=osnap_p, dtype=dtype),
+            g_c=GaussianSketch.draw(keys[3], c, c0, dtype=dtype),
+            s_c=OSNAPSketch.draw(keys[4], s_c, m, p=osnap_p, dtype=dtype),
+            s_r=OSNAPSketch.draw(keys[5], s_r, n, p=osnap_p, dtype=dtype),
+        )
+    else:
+        _check_sketch_shapes(sketches, m, n, sizes)
+        sk = fresh_pytree(sketches)
+    sk = dataclasses.replace(sk, omega=sk.omega.pad_cols(n_pad), s_r=sk.s_r.pad_cols(n_pad))
     return SPSVDState(
         C=jnp.zeros((m, c), dtype),
         R=jnp.zeros((r, n_pad), dtype),
@@ -209,22 +238,31 @@ def spsvd_engine_finalize(
     safe under jit/vmap (the serving head-batch path maps it over heads).
     """
     sk = state.ctx
-    R = truncated_R(state)
-    dt = jnp.promote_types(state.C.dtype, jnp.float32)
-    U_C, _ = jnp.linalg.qr(state.C.astype(dt))  # (m, c)
-    V_R, _ = jnp.linalg.qr(R.T.astype(dt))  # (n, r)
+    with jax.named_scope(SCOPE_SOLVE):
+        R = truncated_R(state)
+        dt = jnp.promote_types(state.C.dtype, jnp.float32)
+        U_C, _ = jnp.linalg.qr(state.C.astype(dt))  # (m, c)
+        V_R, _ = jnp.linalg.qr(R.T.astype(dt))  # (n, r)
 
-    ScU = sk.s_c.apply(U_C.astype(state.C.dtype)).astype(dt)  # (s_c, c)
-    SrV = sk.s_r.apply(V_R.astype(state.C.dtype)).astype(dt)  # (s_r, r)
-    # N = (S_C U_C)† M (V_Rᵀ S_Rᵀ)†  — Fast GMR core (Eqn. 5.3)
-    N = fast_gmr_core(ScU, state.M.astype(dt), SrV.T)
+        ScU = sk.s_c.apply(U_C.astype(state.C.dtype)).astype(dt)  # (s_c, c)
+        SrV = sk.s_r.apply(V_R.astype(state.C.dtype)).astype(dt)  # (s_r, r)
+        # N = (S_C U_C)† M (V_Rᵀ S_Rᵀ)†  — Fast GMR core (Eqn. 5.3)
+        N = fast_gmr_core(ScU, state.M.astype(dt), SrV.T)
 
-    U_N, S, V_Nt = jnp.linalg.svd(N, full_matrices=False)
-    U = U_C @ U_N
-    V = V_R @ V_Nt.T
+        U_N, S, V_Nt = jnp.linalg.svd(N, full_matrices=False)
+        U = U_C @ U_N
+        V = V_R @ V_Nt.T
     if k is not None:
         U, S, V = U[:, :k], S[:k], V[:, :k]
     return U, S, V
+
+
+# Compiled at module scope (one trace per shape and ``k``) and dispatched
+# inside a host span where the host calls it; under a caller's jit or vmap
+# (serving) it traces into the caller's program. The state is not donated.
+spsvd_engine_finalize = spanned("stream/sp_svd/finalize")(
+    jax.jit(spsvd_engine_finalize, static_argnames="k")
+)
 
 
 def sp_svd_finalize(

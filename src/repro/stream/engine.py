@@ -87,12 +87,13 @@ SCOPE_SKETCH = "stream.sketch"  # S_C applied to the chunk or panel, and its ope
 SCOPE_CHUNK_FOLD = "stream.chunk_fold"  # ops.chunk_fold
 SCOPE_MFOLD = "stream.mfold"  # M += (S_C A_L) S_R[:, off:off+L]ᵀ
 SCOPE_ADMIT = "stream.admit"  # scoring and admission: sketch_panel, update_c, fused_step
+SCOPE_COLSKETCH = "stream.colsketch"  # a C update that sketches the panel (SP-SVD's A_L Ω̃_L)
 SCOPE_ROWS = "stream.rows"  # update_r / r_block
 SCOPE_PANEL_KERNEL = "stream.panel_kernel"  # Route B launch + slot-table bookkeeping
 SCOPE_PSUM = "stream.psum"  # the mesh program's collectives
 SCOPE_SOLVE = "finalize.solve"  # the finalizers' core solve
 SCOPES = (
-    SCOPE_SKETCH, SCOPE_CHUNK_FOLD, SCOPE_MFOLD, SCOPE_ADMIT, SCOPE_ROWS,
+    SCOPE_SKETCH, SCOPE_CHUNK_FOLD, SCOPE_MFOLD, SCOPE_ADMIT, SCOPE_COLSKETCH, SCOPE_ROWS,
     SCOPE_PANEL_KERNEL, SCOPE_PSUM, SCOPE_SOLVE,
 )
 
@@ -222,6 +223,9 @@ class PanelOps:
     # must not declare r_block/update_r, and their state's R must be the
     # (0, n_pad) placeholder.
     symmetric: bool = False
+    # Device scope of ``update_c`` in the per-panel body: admission, or
+    # SCOPE_COLSKETCH where the C update is itself a sketch of the panel.
+    c_scope: str = SCOPE_ADMIT
 
     def __post_init__(self):
         """Fail fast at construction: a symmetric (tied-operand) ops derives
@@ -394,7 +398,7 @@ def panel_update(state: PanelState, A_L: jax.Array) -> PanelState:
         with jax.named_scope(SCOPE_MFOLD):
             M = state.M + S_R.cols(off, L).apply_t(sc_a).astype(state.M.dtype)
 
-        with jax.named_scope(SCOPE_ADMIT):
+        with jax.named_scope(ops.c_scope):
             if scores is None:
                 ctx, C = ops.update_c(ctx, state.C, A_L, sc_a, off)
             else:

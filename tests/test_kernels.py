@@ -286,6 +286,83 @@ def test_engine_on_countsketch_kernel_matches_per_panel_oracle(countsketch_kerne
     np.testing.assert_array_equal(results["cur", "scan"].ctx.col_idx, results["cur", "per-panel"].ctx.col_idx)
 
 
+# (p, s, m, n): ragged m and n; s not a multiple of 8; fewer rows than s
+# buckets (the shape of SP-SVD's Omega side, a panel's transpose)
+OSNAP_CASES = [(2, 130, 1000, 300), (4, 130, 1000, 300), (2, 7, 100, 50), (4, 37, 300, 200),
+               (2, 1292 // 4, 64, 700), (4, 1292 // 4, 64, 700)]
+
+
+@pytest.mark.parametrize("case", OSNAP_CASES, ids=lambda c: "p{}_s{}_m{}_n{}".format(*c))
+def test_osnap_kernel_route_matches_segment_sum(case, countsketch_kernel_route):
+    """``OSNAPSketch.apply`` on the kernel route (interpret mode) equals its
+    ``segment_sum`` route: each hash row's term is the kernel's float32
+    segment sum, and the ``p`` terms are added in hash-row order. Bit for
+    bit where the signs ``+-1/sqrt(p)`` are exact (p = 4); at p = 2 each
+    signed row rounds, except where the compiler fuses the kernel's
+    multiply and add (XLA on the CPU does), so the sums agree to float32
+    rounding of the products."""
+    from repro.core.sketching import OSNAPSketch
+    from repro.kernels import ops as kops
+
+    reg = countsketch_kernel_route
+    p, s, m, n = case
+    ks = jax.random.split(jax.random.key(sum(case)), 2)
+    S = OSNAPSketch.draw(ks[0], s, m, p=p)
+    A = jax.random.normal(ks[1], (m, n), jnp.float32)
+    out = jax.jit(S.apply)(A)
+    assert reg.counters == {"sketch.osnap.route.kernel": 1}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kops, "kernel_route_enabled", lambda: False)
+        ref = jax.jit(S.apply)(A)
+    assert reg.counters == {"sketch.osnap.route.kernel": 1, "sketch.osnap.route.segment_sum": 1}
+    assert out.shape == (s, n) and out.dtype == jnp.float32
+    terms = [jax.ops.segment_sum(A * S.signs[j][:, None], S.hashes[j], num_segments=s)
+             for j in range(p)]
+    in_order = terms[0]
+    for t in terms[1:]:
+        in_order = in_order + t
+    if p == 4:
+        np.testing.assert_array_equal(out, in_order)
+        np.testing.assert_array_equal(out, ref)
+    else:
+        scale = float(jnp.max(jnp.abs(ref))) + 1e-30
+        assert float(jnp.max(jnp.abs(out - ref))) <= 1e-6 * scale
+        assert float(jnp.max(jnp.abs(out - in_order))) <= 1e-6 * scale
+
+
+def test_osnap_kernel_route_pad_cols_and_apply_t(countsketch_kernel_route):
+    """``pad_cols`` columns of an OSNAP sketch add nothing on the kernel
+    route, and ``apply_t`` (the Omega side, ``A_L Omega_L^T``) takes it too."""
+    from repro.core.sketching import OSNAPSketch
+
+    reg = countsketch_kernel_route
+    s, m, total, n = 48, 300, 520, 200
+    ks = jax.random.split(jax.random.key(8), 2)
+    S = OSNAPSketch.draw(ks[0], s, m, p=2)
+    A = jax.random.normal(ks[1], (total, n), jnp.float32) + 3.0
+    padded = S.pad_cols(total).apply(A)
+    np.testing.assert_array_equal(padded, S.apply(A[:m]))
+    window = S.pad_cols(total).cols(m - 40, 80)  # straddles the padding
+    B = jax.random.normal(ks[1], (n, 80), jnp.float32)
+    np.testing.assert_allclose(window.apply_t(B), B @ window.materialize().T, rtol=1e-5, atol=1e-5)
+    assert reg.counters == {"sketch.osnap.route.kernel": 3}
+
+
+def test_osnap_apply_off_tpu_is_segment_sum():
+    from repro.core.sketching import OSNAPSketch
+    from repro.obs.metrics import MetricsRegistry, set_registry
+
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    try:
+        S = OSNAPSketch.draw(jax.random.key(9), 32, 64, p=2)
+        S.apply(jnp.ones((64, 8)))
+        S.apply(jnp.ones((64, 2, 4)))  # rank 3
+        assert reg.counters == {"sketch.osnap.route.segment_sum": 2}
+    finally:
+        set_registry(prev)
+
+
 def test_twoside_block_shape_sweep():
     """Same result across BlockSpec tilings (grid decomposition invariance)."""
     s_c, m, n, s_r = 128, 512, 512, 128

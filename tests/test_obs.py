@@ -30,6 +30,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.core.svd import spsvd_engine_finalize, spsvd_engine_init
 from repro.cur import cur_relative_error, streaming_cur_finalize, streaming_cur_init
 from repro.data.synthetic import (
     drifting_spectrum_matrix,
@@ -312,6 +313,13 @@ def _fixed_rows_state():
     )
 
 
+SVD_SIZES = dict(c=8, r=8, c0=24, r0=24, s_c=32, s_r=32)
+
+
+def _svd_state(**sizes):
+    return spsvd_engine_init(jax.random.key(52), M, N, sizes={**SVD_SIZES, **sizes}, panel=PANEL)
+
+
 @contextlib.contextmanager
 def _kernel_route(on: bool):
     from repro.kernels import ops as kops
@@ -330,6 +338,13 @@ ROUTES = {
     "per_panel": (lambda: _fixed_state(False), False, False,
                   {"stream.sketch", "stream.mfold", "stream.admit", "stream.rows"}),
     "kernel": (_gauss_state, True, True, {"stream.sketch", "stream.panel_kernel", "stream.rows"}),
+    # SP-SVD declares no fused hooks: the per-panel body, OSNAP on segment_sum
+    # and (forced) on the countsketch kernel; other shapes than the first, as
+    # the stream's jit caches its trace by shape and not by route
+    "sp_svd": (_svd_state, True, False,
+               {"stream.sketch", "stream.mfold", "stream.colsketch", "stream.rows"}),
+    "sp_svd_kernel": (lambda: _svd_state(c0=40, r0=40), True, True,
+                      {"stream.sketch", "stream.mfold", "stream.colsketch", "stream.rows"}),
 }
 
 
@@ -344,8 +359,9 @@ def _stream_hlo(route: str, scan=None) -> str:
 @pytest.mark.parametrize("route", sorted(ROUTES))
 def test_stream_scopes_in_compiled_hlo(route):
     """Each stage of the route's scan body carries its scope in the compiled
-    program's op names: the fused CountSketch scan, the per-panel body, and
-    Route B's kernel (forced on the CPU)."""
+    program's op names: the fused CountSketch scan, the per-panel body,
+    Route B's kernel (forced on the CPU), and SP-SVD's per-panel body, whose
+    sketched column update is ``stream.colsketch``."""
     assert _scopes_in(_stream_hlo(route)) == ROUTES[route][3]
 
 
@@ -375,8 +391,9 @@ def test_scopes_change_only_metadata(route, monkeypatch):
          streaming_spsd_finalize),
         (lambda: adaptive_spsd_init(jax.random.key(10), N, 8, s=48, panel=PANEL),
          adaptive_spsd_finalize),
+        (_svd_state, spsvd_engine_finalize),
     ],
-    ids=["streaming_cur", "adaptive_cur", "streaming_spsd", "adaptive_spsd"],
+    ids=["streaming_cur", "adaptive_cur", "streaming_spsd", "adaptive_spsd", "sp_svd"],
 )
 def test_finalize_solve_scope(make, finalize):
     """Each finalizer's core solve runs in ``finalize.solve``."""
@@ -491,6 +508,24 @@ def test_default_registry_swap_and_engine_spans():
                          "stream/streaming_cur/finalize"]
     finally:
         set_registry(prev)
+
+
+def test_sp_svd_spans_where_the_host_calls():
+    """SP-SVD's init and finalize carry ``stream/sp_svd/*`` spans where the
+    host calls them, beside the engine's scan span; OSNAP's route counter is
+    recorded once per trace."""
+    prev = set_registry(MetricsRegistry())
+    try:
+        jax.clear_caches()
+        st = stream_panels(_svd_state(), _A(), PANEL)
+        jax.block_until_ready(spsvd_engine_finalize(st))
+        reg = default_registry()
+        assert [s.name for s in reg.spans] == ["stream/sp_svd/init", "stream/sp_svd/scan",
+                                               "stream/sp_svd/finalize"]
+        assert reg.counters.get("sketch.osnap.route.segment_sum", 0) > 0
+    finally:
+        set_registry(prev)
+        jax.clear_caches()
 
 
 def test_record_stream_telemetry():

@@ -94,3 +94,83 @@ def test_orthonormal_outputs(A):
     np.testing.assert_allclose(U.T @ U, np.eye(U.shape[1]), atol=1e-4)
     np.testing.assert_allclose(V.T @ V, np.eye(V.shape[1]), atol=1e-4)
     assert bool(jnp.all(S[:-1] >= S[1:]))  # sorted singular values
+
+
+# ------------------------------------------- engine against a plain reference
+
+# a ragged stream (250 columns in panels of 64) with sketch sizes that embed
+# the bases well (s = 4c), so the floored core solve and a plain pinv agree
+ENGINE_SIZES = dict(c=20, r=20, c0=60, r0=60, s_c=80, s_r=80)
+
+
+def _plain_spsvd(A, sk):
+    """Algorithm 3 in plain float32 at ``highest`` from the dense forms of
+    the engine's sketches: C, R, M, then steps 10-13 with pinv solves."""
+    with jax.default_matmul_precision("highest"):
+        d = {name: np.asarray(getattr(sk, name).materialize()) for name in
+             ("psi", "g_r", "omega", "g_c", "s_c", "s_r")}
+        m, n = A.shape
+        A = jnp.asarray(A)
+        C = (A @ d["omega"][:, :n].T) @ d["g_c"].T
+        R = d["g_r"] @ (d["psi"] @ A)
+        M = d["s_c"] @ A @ d["s_r"][:, :n].T
+        Q_C, _ = jnp.linalg.qr(C)
+        Q_R, _ = jnp.linalg.qr(R.T)
+        N = jnp.linalg.pinv(d["s_c"] @ Q_C) @ M @ jnp.linalg.pinv(d["s_r"][:, :n] @ Q_R).T
+        return C, R, M, Q_C @ N @ Q_R.T
+
+
+@pytest.mark.parametrize("route", ["segment_sum", "kernel"])
+def test_engine_matches_plain_reference(route, monkeypatch):
+    """``spsvd_engine_init`` on injected sketches, ``stream_panels`` (the
+    legacy scan body, ragged tail) and ``spsvd_engine_finalize`` give the
+    C, R, M and reconstruction of a plain float32 Algorithm 3 on the same
+    sketches; with OSNAP on the ``countsketch`` kernel (interpret mode) too."""
+    from repro.core.sketching import GaussianSketch, OSNAPSketch
+    from repro.core.svd import SPSVDSketches, spsvd_engine_finalize, spsvd_engine_init
+    from repro.kernels import ops as kops
+    from repro.stream import stream_panels
+
+    if route == "kernel":
+        monkeypatch.setattr(kops, "kernel_route_enabled", lambda: True)
+    jax.clear_caches()
+    m, n, panel = 300, 250, 64
+    A = powerlaw_matrix(jax.random.key(11), m, n, 1.0)
+    z = ENGINE_SIZES
+    ks = jax.random.split(jax.random.key(12), 6)
+    sk = SPSVDSketches(
+        psi=OSNAPSketch.draw(ks[0], z["r0"], m), g_r=GaussianSketch.draw(ks[1], z["r"], z["r0"]),
+        omega=OSNAPSketch.draw(ks[2], z["c0"], n), g_c=GaussianSketch.draw(ks[3], z["c"], z["c0"]),
+        s_c=OSNAPSketch.draw(ks[4], z["s_c"], m), s_r=OSNAPSketch.draw(ks[5], z["s_r"], n),
+    )
+    C, R, M, recon = _plain_spsvd(A, sk)
+    with jax.default_matmul_precision("highest"):
+        st = spsvd_engine_init(jax.random.key(0), m, n, sizes=z, panel=panel, sketches=sk)
+        st = stream_panels(st, A, panel)
+        U, S, V = spsvd_engine_finalize(st)
+        got = (U * S[None, :]) @ V.T
+    jax.clear_caches()
+
+    def rel(a, b):
+        return float(jnp.linalg.norm(jnp.asarray(a) - b) / jnp.linalg.norm(b))
+
+    assert rel(st.C, C) < 1e-5 and rel(st.R[:, :n], R) < 1e-5 and rel(st.M, M) < 1e-5
+    assert not bool(jnp.any(st.R[:, n:]))  # the padded tail stays zero
+    assert rel(got, recon) < 1e-4
+
+
+def test_injected_sketches_equal_drawn():
+    """``spsvd_engine_init(sketches=...)`` with the operators the key draws
+    gives the drawn path's state, padding included; a wrong shape raises."""
+    from repro.core.svd import spsvd_engine_init
+
+    m, n, panel = 120, 100, 32
+    drawn = spsvd_engine_init(jax.random.key(5), m, n, sizes=ENGINE_SIZES, panel=panel)
+    bare = spsvd_engine_init(jax.random.key(5), m, n, sizes=ENGINE_SIZES)
+    given = spsvd_engine_init(jax.random.key(6), m, n, sizes=ENGINE_SIZES, panel=panel,
+                              sketches=bare.ctx)
+    assert jax.tree_util.tree_structure(given) == jax.tree_util.tree_structure(drawn)
+    for a, b in zip(jax.tree_util.tree_leaves(given), jax.tree_util.tree_leaves(drawn)):
+        np.testing.assert_array_equal(a, b)
+    with pytest.raises(ValueError, match="omega"):
+        spsvd_engine_init(jax.random.key(6), m, n + 1, sizes=ENGINE_SIZES, sketches=bare.ctx)

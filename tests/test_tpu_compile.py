@@ -146,3 +146,43 @@ def test_countsketch_route_compiles_on_four_chips(topo, where, monkeypatch):
     text = jax.jit(fn).lower(sketch, a).compile().as_text()
     jax.clear_caches()
     assert ("tpu_custom_call" in text) == (where == "shard_map")
+
+
+# (operand rows, operand cols, s, apply or apply_t): OSNAP (p = 2) at the
+# single-pass SVD cell's shapes — S_C (s = 544) and Psi (s = 1292) over the
+# rows of a (32768, 512) panel, and Omega (s = 1292) over its columns, which
+# apply_t sketches as the rows of the panel's (512, 32768) transpose
+OSNAP_CELL_SHAPES = [(32768, 512, 544, "apply"), (32768, 512, 1292, "apply"),
+                     (32768, 512, 1292, "apply_t")]
+
+
+@pytest.mark.parametrize("shape", OSNAP_CELL_SHAPES, ids=lambda w: "m{}_n{}_s{}_{}".format(*w))
+def test_osnap_route_compiles_for_v5e_at_cell_shapes(one_chip, shape, monkeypatch):
+    """``OSNAPSketch.apply`` as on a TPU: one kernel call per hash row, and
+    no operand-sized array made besides the panel's transpose that
+    ``apply_t`` sketches."""
+    from repro.core.sketching import OSNAPSketch
+
+    monkeypatch.setattr(ops, "kernel_route_enabled", lambda: True)
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    jax.clear_caches()
+    rows, cols, s, how = shape
+    src = rows if how == "apply" else cols
+
+    def arg(sh, dt):
+        return jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+
+    sketch = OSNAPSketch(hashes=arg((2, src), jnp.int32), signs=arg((2, src), jnp.float32),
+                         s=s, p=2)
+    text = jax.jit(lambda S, a: getattr(S, how)(a)).lower(
+        sketch, arg((rows, cols), jnp.float32)).compile().as_text()
+    jax.clear_caches()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # a bitcast is a view; the transpose is one relayout copy of the panel
+    made = [line for line in text.splitlines()
+            if ("= f32[32768,512]{" in line or "= f32[512,32768]{" in line)
+            and "parameter(" not in line and " bitcast(" not in line]
+    if how == "apply":
+        assert not made, made
+    else:
+        assert len(made) == 1 and (" copy(" in made[0] or " transpose(" in made[0]), made
