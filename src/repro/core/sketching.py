@@ -23,6 +23,10 @@ columns so that ``cols()`` windows reaching past the true source dim stay
 valid slices that contribute nothing (the contract zero-padded ragged tail
 panels rely on; see ``repro.stream.engine``).
 
+The hashed sketches (CountSketch, OSNAP) add ``transpose_apply(Y)`` —
+``S.T @ Y`` (Y is (s, k)), a gather of the rows of ``Y`` their hashes
+name.
+
 All randomness is fully determined by an explicit ``jax.random`` key so that
 sketches drawn on different data-parallel workers from a shared seed are
 bit-identical (gradient compression relies on ``Σᵢ(Gᵢ Ω) = (Σᵢ Gᵢ) Ω``).
@@ -185,6 +189,20 @@ _register(SRHTSketch, ("signs", "row_idx"), ("m", "m_pad"))
 # ---------------------------------------------------------------------------
 
 
+def _hashed_transpose_apply(hashes: jax.Array, signs: jax.Array, Y: jax.Array) -> jax.Array:
+    """``Sᵀ Y`` for the hashed sketch with ``(p, m)`` ``hashes`` and
+    ``signs``: row ``i`` is ``Σ_j signs[j, i] · Y[hashes[j, i]]``, the ``p``
+    terms added in hash-row order in float32 (at least). A gather of ``p·m``
+    rows of ``Y``; zero-signed ``pad_cols`` columns give zero rows."""
+    dt = jnp.result_type(Y.dtype, signs.dtype)
+    acc = jnp.promote_types(dt, jnp.float32)
+    out = None
+    for h, sg in zip(hashes, signs):
+        term = _bcast_vec(sg.astype(acc), Y.ndim) * Y[h].astype(acc)
+        out = term if out is None else out + term
+    return out.astype(dt)
+
+
 def _takes_kernel(A: jax.Array) -> bool:
     """Does a hashed sketch (CountSketch, OSNAP) apply to ``A`` through the
     ``countsketch`` Pallas kernel? On a TPU (``kernel_route_enabled``), for a
@@ -238,6 +256,10 @@ class CountSketch:
 
     def apply_t(self, A: jax.Array) -> jax.Array:
         return self.apply(A.T).T
+
+    def transpose_apply(self, Y: jax.Array) -> jax.Array:
+        """``Sᵀ Y`` for ``Y`` (s, k): row ``i`` is ``signs[i] · Y[hashes[i]]``."""
+        return _hashed_transpose_apply(self.hashes[None], self.signs[None], Y)
 
     def materialize(self) -> jax.Array:
         S = jnp.zeros((self.s, self.m), self.signs.dtype)
@@ -322,6 +344,12 @@ class OSNAPSketch:
 
     def apply_t(self, A: jax.Array) -> jax.Array:
         return self.apply(A.T).T
+
+    def transpose_apply(self, Y: jax.Array) -> jax.Array:
+        """``Sᵀ Y`` for ``Y`` (s, k): row ``i`` is ``Σ_j signs[j, i] ·
+        Y[hashes[j, i]]``, the ``p`` terms added in hash-row order in
+        float32. No kernel: a gather of ``p·m`` rows."""
+        return _hashed_transpose_apply(self.hashes, self.signs, Y)
 
     def materialize(self) -> jax.Array:
         S = jnp.zeros((self.s, self.m), self.signs.dtype)

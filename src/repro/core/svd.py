@@ -103,10 +103,11 @@ def _svd_core_sketches(sk: SPSVDSketches):
 
 
 def _svd_update_c(sk: SPSVDSketches, C, A_L, sc_a, off):
-    # C += A_L · Ω̃[cols]  with  Ω̃[cols] = Ω[:, cols]ᵀ · G_Cᵀ  (never materialized)
-    L = A_L.shape[1]
-    a_omega = sk.omega.cols(off, L).apply_t(A_L)  # A_L (m,L) × Ω[:,cols]ᵀ (L,c0) → (m, c0)
-    return sk, C + sk.g_c.apply_t(a_omega).astype(C.dtype)  # (m, c)
+    # C += A_L · Ω̃[cols]  with  Ω̃[cols] = Ω[:, cols]ᵀ · G_Cᵀ (L, c), a gather
+    # that needs no pass over A; the panel is read once, by one matmul at
+    # HIGH, so that on a TPU A_L is not rounded to one bfloat16 pass
+    W = sk.omega.cols(off, A_L.shape[1]).transpose_apply(sk.g_c.mat.T)
+    return sk, C + jnp.matmul(A_L, W, precision=jax.lax.Precision.HIGH).astype(C.dtype)
 
 
 def _svd_r_block(sk: SPSVDSketches, A_L, off):

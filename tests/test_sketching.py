@@ -33,6 +33,38 @@ def test_cols_slicing(kind):
     )
 
 
+# (family, p): CountSketch, and OSNAP at p = 1, 2 and 4
+HASHED = [("countsketch", 1), ("osnap", 1), ("osnap", 2), ("osnap", 4)]
+
+
+@pytest.mark.parametrize("kind,p", HASHED, ids=lambda v: str(v))
+def test_transpose_apply_matches_materialized(kind, p):
+    """``transpose_apply(Y)`` is ``materialize().T @ Y``: on the whole
+    sketch, whose ``pad_cols`` columns give zero rows; on a ``cols(off, L)``
+    window with a traced ``off`` under jit, here straddling the padding; and
+    under vmap over a batch of sketches."""
+    s, m, total, L = 48, 300, 360, 64
+    ks = jax.random.split(jax.random.key(3 + p), 3)
+    S = draw_sketch(ks[0], kind, s, m, p=p).pad_cols(total)
+    Y = jax.random.normal(ks[1], (s, 7))
+    want = jnp.matmul(S.materialize().T, Y, precision="highest")
+    got = S.transpose_apply(Y)
+    assert got.shape == (total, 7) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert not bool(jnp.any(got[m:]))
+
+    window = jax.jit(lambda S, off, Y: S.cols(off, L).transpose_apply(Y))
+    for off in (0, 130, m - 20):
+        np.testing.assert_allclose(window(S, off, Y), want[off:off + L], rtol=1e-6, atol=1e-6)
+
+    batch = [draw_sketch(k, kind, s, m, p=p) for k in jax.random.split(ks[2], 3)]
+    Ys = jax.random.normal(ks[2], (3, s, 7))
+    stacked = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *batch)
+    mapped = jax.vmap(lambda S, Y: S.transpose_apply(Y))(stacked, Ys)
+    for i, one in enumerate(batch):
+        np.testing.assert_allclose(mapped[i], one.transpose_apply(Ys[i]), rtol=0, atol=0)
+
+
 @pytest.mark.slow
 @settings(deadline=None, max_examples=15)
 @given(

@@ -98,7 +98,7 @@ def test_orthonormal_outputs(A):
 
 # ------------------------------------------- engine against a plain reference
 
-# a ragged stream (250 columns in panels of 64) with sketch sizes that embed
+# a ragged stream (250 columns in panels of 64 or 32) with sketch sizes that embed
 # the bases well (s = 4c), so the floored core solve and a plain pinv agree
 ENGINE_SIZES = dict(c=20, r=20, c0=60, r0=60, s_c=80, s_r=80)
 
@@ -120,12 +120,23 @@ def _plain_spsvd(A, sk):
         return C, R, M, Q_C @ N @ Q_R.T
 
 
-@pytest.mark.parametrize("route", ["segment_sum", "kernel"])
-def test_engine_matches_plain_reference(route, monkeypatch):
+# (route, panel): panels on each side of c0 = 60; both form the C update as
+# A_L (G_C Ω_L)ᵀ
+ENGINE_CASES = [
+    pytest.param("segment_sum", 64, id="segment_sum"),
+    pytest.param("kernel", 64, id="kernel"),
+    pytest.param("segment_sum", 32, id="segment_sum-panel32"),
+    pytest.param("kernel", 32, id="kernel-panel32"),
+]
+
+
+@pytest.mark.parametrize("route,panel", ENGINE_CASES)
+def test_engine_matches_plain_reference(route, panel, monkeypatch):
     """``spsvd_engine_init`` on injected sketches, ``stream_panels`` (the
     legacy scan body, ragged tail) and ``spsvd_engine_finalize`` give the
     C, R, M and reconstruction of a plain float32 Algorithm 3 on the same
-    sketches; with OSNAP on the ``countsketch`` kernel (interpret mode) too."""
+    sketches; with OSNAP on the ``countsketch`` kernel (interpret mode) too,
+    and with panels narrower and wider than c0."""
     from repro.core.sketching import GaussianSketch, OSNAPSketch
     from repro.core.svd import SPSVDSketches, spsvd_engine_finalize, spsvd_engine_init
     from repro.kernels import ops as kops
@@ -134,7 +145,7 @@ def test_engine_matches_plain_reference(route, monkeypatch):
     if route == "kernel":
         monkeypatch.setattr(kops, "kernel_route_enabled", lambda: True)
     jax.clear_caches()
-    m, n, panel = 300, 250, 64
+    m, n = 300, 250
     A = powerlaw_matrix(jax.random.key(11), m, n, 1.0)
     z = ENGINE_SIZES
     ks = jax.random.split(jax.random.key(12), 6)

@@ -150,8 +150,8 @@ def test_countsketch_route_compiles_on_four_chips(topo, where, monkeypatch):
 
 # (operand rows, operand cols, s, apply or apply_t): OSNAP (p = 2) at the
 # single-pass SVD cell's shapes — S_C (s = 544) and Psi (s = 1292) over the
-# rows of a (32768, 512) panel, and Omega (s = 1292) over its columns, which
-# apply_t sketches as the rows of the panel's (512, 32768) transpose
+# rows of a (32768, 512) panel, and an s = 1292 sketch over its columns,
+# which apply_t sketches as the rows of the panel's (512, 32768) transpose
 OSNAP_CELL_SHAPES = [(32768, 512, 544, "apply"), (32768, 512, 1292, "apply"),
                      (32768, 512, 1292, "apply_t")]
 
@@ -186,3 +186,33 @@ def test_osnap_route_compiles_for_v5e_at_cell_shapes(one_chip, shape, monkeypatc
         assert not made, made
     else:
         assert len(made) == 1 and (" copy(" in made[0] or " transpose(" in made[0]), made
+
+
+def test_spsvd_c_update_compiles_for_v5e_at_cell_shapes(one_chip, monkeypatch):
+    """SP-SVD's C update at the single-pass SVD cell's shapes (a 512-column
+    panel, c0 = 1292, c = 384), as on a TPU: ``A_L (G_C Omega_L)^T``, one
+    matmul at HIGH — no kernel call, no transpose of the panel and no
+    (m, c0) array made."""
+    from repro.core.sketching import GaussianSketch, OSNAPSketch
+    from repro.core.svd import SPSVDSketches, _svd_update_c
+
+    monkeypatch.setattr(ops, "kernel_route_enabled", lambda: True)
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    jax.clear_caches()
+    m, n, L, c, c0 = 32768, 32768, 512, 384, 1292
+
+    def arg(sh, dt):
+        return jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+
+    omega = OSNAPSketch(hashes=arg((2, n), jnp.int32), signs=arg((2, n), jnp.float32),
+                        s=c0, p=2)
+    g_c = GaussianSketch(arg((c, c0), jnp.float32))
+    sk = SPSVDSketches(psi=None, g_r=None, omega=omega, g_c=g_c, s_c=None, s_r=None)
+    text = jax.jit(lambda sk, C, a, off: _svd_update_c(sk, C, a, None, off)[1]).lower(
+        sk, arg((m, c), jnp.float32), arg((m, L), jnp.float32), arg((), jnp.int32)
+    ).compile().as_text()
+    jax.clear_caches()
+    assert "tpu_custom_call" not in text
+    made = [line for line in text.splitlines()
+            if any(f"= f32[{sh}]{{" in line for sh in ("512,32768", "32768,1292", "1292,32768"))]
+    assert not made, made
