@@ -1,8 +1,8 @@
-"""Shared benchmark utilities: timing, synthetic matrices, CSV rows, and
-the standard ``BENCH_<module>.json`` artifact writer (every table/figure
+"""Shared utilities of the paper's accuracy experiments: CPU timing, the
+synthetic inputs of ``repro.data.synthetic`` (re-exported), and the
+standard ``BENCH_<module>.json`` artifact writer that every table/figure
 module — ``gmr_error``, ``cur_decomp``, ``spsd_approx``,
-``single_pass_svd``, ``sketch_perf``, ``stream_bench`` — writes through
-it; ``check_regression`` gates any artifact with a committed baseline)."""
+``single_pass_svd``, ``stream_bench`` — writes through."""
 
 from __future__ import annotations
 
@@ -12,10 +12,10 @@ import platform
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.data.synthetic import (  # noqa: F401 — re-export
+    clustered_points,
     drifting_spectrum_matrix,
     late_spike_matrix,
     lowrank_plus_noise,
@@ -23,6 +23,7 @@ from repro.data.synthetic import (  # noqa: F401 — re-export
     sparse_matrix,
     spiked_decay_matrix,
     spiked_rows_matrix,
+    tune_rbf_sigma,
 )
 
 
@@ -97,31 +98,3 @@ def time_calls_interleaved(fns: dict, warmup: int = 1, rounds: int = 7) -> dict:
             jax.block_until_ready(fn())
             best[name] = min(best[name], (time.perf_counter() - t0) * 1e6)
     return best
-
-
-def clustered_points(key, n: int, d: int, n_clusters: int = 10, spread: float = 1.0):
-    """Clustered Gaussian data for RBF kernels (§6.2 datasets substitution)."""
-    k1, k2, k3 = jax.random.split(key, 3)
-    centers = jax.random.normal(k1, (n_clusters, d)) * 3.0
-    assign = jax.random.randint(k2, (n,), 0, n_clusters)
-    return centers[assign] + spread * jax.random.normal(k3, (n, d))
-
-
-def tune_rbf_sigma(X, k: int = 15, target_eta: float = 0.7, iters: int = 20) -> float:
-    """Bisect σ so that η = ||K_k||²_F/||K||²_F ≈ target (paper Table 6 protocol)."""
-    from repro.core.spsd import rbf_kernel_oracle
-
-    lo, hi = 1e-6, 1e2
-    for _ in range(iters):
-        mid = float(np.sqrt(lo * hi))
-        K = rbf_kernel_oracle(X, mid)(None, None)
-        ev = jnp.linalg.eigvalsh(K.astype(jnp.float64) if False else K)
-        ev2 = jnp.sort(ev**2)[::-1]
-        eta = float(jnp.sum(ev2[:k]) / jnp.sum(ev2))
-        if eta > target_eta:
-            lo = mid  # kernel too close to low rank? raise sigma decreases eta
-        else:
-            hi = mid
-        if abs(eta - target_eta) < 0.05:
-            return mid
-    return float(np.sqrt(lo * hi))

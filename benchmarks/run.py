@@ -1,10 +1,11 @@
-"""Benchmark harness: one module per paper table/figure.
+"""The paper's accuracy experiments: one module per table/figure.
 
   PYTHONPATH=src python -m benchmarks.run [--quick] [--only gmr_error,...]
 
 Prints ``name,us_per_call,derived`` CSV rows (the skeleton contract). A
 module that raises prints a ``<module>/ERROR`` row, the remaining modules
-still run, and the harness then exits non-zero.
+still run, and the harness then exits non-zero. ``us_per_call`` is CPU
+wall-clock; speed is measured on the chip by ``bench/``.
 """
 
 from __future__ import annotations
@@ -23,26 +24,14 @@ def main() -> None:
     from repro.launch.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    from . import (
-        cur_decomp,
-        gmr_error,
-        roofline,
-        serve_bench,
-        single_pass_svd,
-        sketch_perf,
-        spsd_approx,
-        stream_bench,
-    )
+    from . import cur_decomp, gmr_error, single_pass_svd, spsd_approx, stream_bench
 
     modules = {
         "gmr_error": gmr_error,        # paper Fig. 1  (§6.1)
         "cur_decomp": cur_decomp,      # paper §1 application 1 (repro/cur/)
         "spsd_approx": spsd_approx,    # paper Fig. 2 + Table 7 (§6.2)
         "single_pass_svd": single_pass_svd,  # paper Fig. 3 (§6.3)
-        "sketch_perf": sketch_perf,    # kernel layer
-        "roofline": roofline,          # §Roofline terms from dry-run artifacts
         "stream_bench": stream_bench,  # streaming engine: adaptive/evict/rows + DP parity
-        "serve_bench": serve_bench,    # serving: decode throughput + KV compression
     }
     if args.only:
         keep = set(args.only.split(","))

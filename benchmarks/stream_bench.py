@@ -17,23 +17,14 @@ Rows (→ ``BENCH_stream.json`` via ``benchmarks.common.write_bench_json``):
 * ``stream/cur/rows/<m>x<n>/fixed|adaptive`` — fixed pre-pass uniform rows
   vs in-stream row admission (equal r budget, identical adaptive columns)
   on spiked-rows matrices, plus a ``row_win`` PASS/FAIL row.
-* ``stream/cur/<m>x<n>/adaptive+tel/w<W>`` — the adaptive config re-timed
-  with the in-scan telemetry frame attached (``telemetry=True``); the
-  ``+tel`` suffix pairs each row with its untelemetered twin so
-  ``check_regression.py --overhead-suffix "+tel"`` can gate the overhead
-  (acceptance: ≤ 1.3×) *within* one artifact, host-invariantly.
 * ``stream/obs/est/<family>/<m>x<n>`` — the a-posteriori error estimator
   (``repro.obs.estimate_rel_error``) vs the true relative Frobenius error
   on each stream family; ``ratio`` must sit inside the 2× band.
 * ``stream/spsvd/<m>x<n>/parity/w<W>``       — max |Δ| between DP-sharded
   and single-host SP-SVD accumulators (exactness evidence).
-* ``stream/resilient/<m>x<n>/w<W>[+ckpt8]`` — the resilient driver
-  (``run_resilient_stream`` / ``run_resilient_sharded_stream``) with and
-  without packed checkpointing at cadence 8 (one non-durable single-file
-  save per 8 chunks, plus a final save). The ``+ckpt8`` suffix pairs each
-  row with its checkpoint-free twin so ``check_regression.py
-  --overhead-suffix "+ckpt8" --overhead-threshold 1.1`` gates the
-  checkpoint overhead *within* one artifact (acceptance: ≤ 1.1×).
+
+The ``us_per_call`` of these rows is CPU wall-clock, a rehearsal and not
+a speed claim: speed is measured on the chip by ``bench/``.
 
 When ``--out-dir`` is given the run's host metrics (stream telemetry
 summaries + profiling spans, via :mod:`repro.obs.metrics`) are dumped as
@@ -46,8 +37,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import shutil
-import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -57,11 +46,8 @@ from repro.core.svd import sp_svd_init
 from repro.cur import cur_relative_error, select_rows, streaming_cur_finalize, streaming_cur_init
 from repro.obs import MetricsRegistry, default_registry, estimate_rel_error, set_registry
 from repro.stream import (
-    ArrayPanelSource,
     adaptive_cur_finalize,
     adaptive_cur_init,
-    run_resilient_sharded_stream,
-    run_resilient_stream,
     simulate_sharded_stream,
     stream_panels,
 )
@@ -103,7 +89,7 @@ def _win_row(name: str, lose_err: float, win_err: float, label: str) -> dict:
 def run_adaptive_vs_uniform(shapes, trials: int, quick: bool) -> list:
     """PR-2 scenario kept intact: admission vs fixed-uniform at equal c.
 
-    Timing methodology (perf acceptance rows): every (method, workers)
+    Timing methodology (CPU wall-clock): every (method, workers)
     configuration of a shape is timed **interleaved** (one call per config
     per round, min over rounds — see
     :func:`benchmarks.common.time_calls_interleaved`) so the
@@ -155,19 +141,12 @@ def run_adaptive_vs_uniform(shapes, trials: int, quick: bool) -> list:
             key, m, n, ci0, ri, sketch="countsketch", panel=panel))
         adapt_init = jax.jit(lambda key: adaptive_cur_init(
             key, m, n, c, ri, sketch="countsketch", panel=panel, panel_cap=panel_cap))
-        # telemetered twin of the adaptive config: identical policy + shapes,
-        # plus the in-scan diagnostics frame — its rows pair with the plain
-        # adaptive rows via the "+tel" suffix for the overhead gate
-        adapt_tel_init = jax.jit(lambda key: adaptive_cur_init(
-            key, m, n, c, ri, sketch="countsketch", panel=panel,
-            panel_cap=panel_cap, telemetry=True))
 
         def once(method, workers):
             if method == "fixed-uniform":
                 st = fixed_init(jax.random.key(200))
                 return streaming_cur_finalize(_stream(st, A, panel, workers)).U
-            init = adapt_tel_init if method == "adaptive+tel" else adapt_init
-            st = init(jax.random.key(200))
+            st = adapt_init(jax.random.key(200))
             return adaptive_cur_finalize(_stream(st, A, panel, workers)).U
 
         # Cyclic measurement order keeps each w's fixed/adaptive pair and the
@@ -176,12 +155,10 @@ def run_adaptive_vs_uniform(shapes, trials: int, quick: bool) -> list:
         fns = {
             (method, workers): (lambda method=method, workers=workers: once(method, workers))
             for workers in (4, 1, 2)
-            for method in ("fixed-uniform", "adaptive", "adaptive+tel")
+            for method in ("fixed-uniform", "adaptive")
         }
         # rounds stretch the session across several contention cycles of the
-        # shared container, so every config touches its true floor; the quick
-        # lane still needs enough rounds that the telemetry-overhead gate
-        # (±1.3x on paired rows) sits on converged minima, not first-touch noise
+        # shared container, so every config touches its true floor
         times = time_calls_interleaved(fns, warmup=1, rounds=40 if quick else 100)
         for workers in (1, 2, 4):
             for method in ("fixed-uniform", "adaptive"):
@@ -195,12 +172,6 @@ def run_adaptive_vs_uniform(shapes, trials: int, quick: bool) -> list:
                     "derived": derived,
                     "_rel_err": rel,
                 })
-            overhead = times[("adaptive+tel", workers)] / max(times[("adaptive", workers)], 1e-9)
-            rows.append({
-                "name": f"stream/cur/{m}x{n}/adaptive+tel/w{workers}",
-                "us_per_call": round(times[("adaptive+tel", workers)], 1),
-                "derived": f"telemetry_overhead={overhead:.2f}x;c={c};panel={panel}",
-            })
         for workers in (1, 2, 4):
             win = errs[("fixed-uniform", workers)] / max(errs[("adaptive", workers)], 1e-12)
             rows.append({
@@ -382,86 +353,6 @@ def run_spsvd_parity(shapes) -> list:
     return rows
 
 
-def run_resilient_overhead(quick: bool) -> list:
-    """Checkpoint-overhead acceptance rows for the resilient driver.
-
-    A tall fixed-CUR stream (8192×1024, panel 16, 4-panel chunks → 16
-    chunks at w1) is driven through the resilient driver with and without
-    checkpointing at cadence 8, on 1/2/4 workers, timed interleaved like
-    the other perf rows. The geometry is deliberately compute-bound: each
-    chunk costs ~milliseconds of scan work, so the ~0.5 ms packed
-    non-durable save amortizes to a few percent — the property the ≤ 1.1×
-    ``+ckpt8`` gate locks in.
-
-    Methodology notes (hard-won stability constraints):
-
-    * Checkpoint dirs live on tmpfs (``/dev/shm`` when present) so disk
-      tail latency doesn't hit only the ``+ckpt8`` side of a pair.
-    * ONE directory per worker config, reused across all rounds with
-      ``resume=False`` (write-only): every call overwrites the same step
-      ids in place, so no per-round dir accumulation, no GC churn, and no
-      memory-pressure spikes from hundreds of stale tmpfs checkpoints.
-    * Saves are non-durable (no fsync): the rename commit is already
-      atomic against the process-crash fault model the driver defends.
-    """
-    rows = []
-    m, n, panel, chunk_panels, cadence = 8192, 1024, 16, 4, 8
-    A, _pos = spiked_decay_matrix(jax.random.key(m + n), m, n)
-    ci = jax.random.choice(jax.random.key(31), n, (16,), replace=False)
-    ri = jax.random.choice(jax.random.key(32), m, (16,), replace=False)
-    src = ArrayPanelSource(A, panel)
-
-    def once(workers, ckpt_dir):
-        st = streaming_cur_init(jax.random.key(7), m, n, ci, ri, panel=panel)
-        if workers == 1:
-            st, _rep = run_resilient_stream(
-                st, src, chunk_panels=chunk_panels, ckpt_dir=ckpt_dir,
-                ckpt_every=cadence, keep_last=2, resume=False,
-            )
-        else:
-            st, _reps = run_resilient_sharded_stream(
-                st, src, workers, chunk_panels=chunk_panels, ckpt_dir=ckpt_dir,
-                ckpt_every=cadence, keep_last=2, resume=False,
-            )
-        return st.C
-
-    root = "/dev/shm" if os.path.isdir("/dev/shm") else None
-    base = tempfile.mkdtemp(prefix="bench_resilient_", dir=root)
-    fns = {}
-    for workers in (4, 1, 2):
-        fns[f"w{workers}"] = lambda workers=workers: once(workers, None)
-        d = os.path.join(base, f"w{workers}")
-        fns[f"w{workers}+ckpt8"] = lambda workers=workers, d=d: once(workers, d)
-    try:
-        # enough rounds that every config's min-floor converges: the gate
-        # margin is only ~3% at w4 (4 final saves on an ~88 ms call), so
-        # first-touch noise on either side of a pair must be rotated out
-        times = time_calls_interleaved(fns, warmup=1, rounds=20 if quick else 30)
-    finally:
-        shutil.rmtree(base, ignore_errors=True)
-
-    num_panels = n // panel
-    for workers in (1, 2, 4):
-        per_worker = -(-num_panels // workers)
-        chunks = -(-per_worker // chunk_panels)
-        base_t = times[f"w{workers}"]
-        ckpt_t = times[f"w{workers}+ckpt8"]
-        rows.append({
-            "name": f"stream/resilient/{m}x{n}/w{workers}",
-            "us_per_call": round(base_t, 1),
-            "derived": f"panel={panel};chunk_panels={chunk_panels}"
-                       f";chunks_per_worker={chunks}",
-        })
-        overhead = ckpt_t / max(base_t, 1e-9)
-        rows.append({
-            "name": f"stream/resilient/{m}x{n}/w{workers}+ckpt8",
-            "us_per_call": round(ckpt_t, 1),
-            "derived": f"ckpt_overhead={overhead:.2f}x;cadence={cadence}"
-                       f";packed;durable=False;tmpfs={root is not None}",
-        })
-    return rows
-
-
 def run(trials: int = 3, quick: bool = False) -> list:
     shapes = [(384, 320, 64)] if quick else [(1024, 768, 128), (2048, 1024, 128)]
     rows = run_adaptive_vs_uniform(shapes, trials, quick)
@@ -469,7 +360,6 @@ def run(trials: int = 3, quick: bool = False) -> list:
     rows += run_row_admission(shapes, trials)
     rows += run_error_estimator(shapes, trials)
     rows += run_spsvd_parity(shapes)
-    rows += run_resilient_overhead(quick)
     return rows
 
 

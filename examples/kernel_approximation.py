@@ -9,12 +9,10 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import clustered_points, tune_rbf_sigma
 from repro.core import (
     fast_spsd_wang,
     faster_spsd,
@@ -23,6 +21,7 @@ from repro.core import (
     rbf_kernel_oracle,
     spsd_error_ratio,
 )
+from repro.data import clustered_points, tune_rbf_sigma
 
 n, d, k = 1200, 32, 15
 X = clustered_points(jax.random.key(0), n, d, n_clusters=10, spread=0.7)

@@ -3,8 +3,9 @@
 Matrix generators (:func:`powerlaw_matrix`, :func:`sparse_matrix`,
 :func:`lowrank_plus_noise`) are the offline substitutions for the paper's
 LIBSVM datasets (matched spectral / sparsity profiles, DESIGN.md §8) and
-the ground truth for the CUR / GMR / SVD test-and-benchmark suites —
-``benchmarks/common.py`` re-exports them.
+the ground truth for the CUR / GMR / SVD test-and-benchmark suites;
+:func:`clustered_points` and :func:`tune_rbf_sigma` give the RBF-kernel
+inputs of §6.2.
 
 Stateless-by-construction: ``batch_at(step)`` derives every batch from
 ``fold_in(seed, step)``, so restart-from-checkpoint only needs the step
@@ -135,6 +136,35 @@ def drifting_spectrum_matrix(
         Rf = jax.random.normal(kR, (rank, hi - lo), dtype)
         B = B.at[:, lo:hi].add((ramp ** b) * (L @ Rf) / np.sqrt(rank))
     return B, jnp.asarray(bounds, jnp.int32)
+
+
+def clustered_points(key, n: int, d: int, n_clusters: int = 10, spread: float = 1.0):
+    """Clustered Gaussian points for RBF kernels (the substitution for the
+    §6.2 datasets)."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    centers = jax.random.normal(k1, (n_clusters, d)) * 3.0
+    assign = jax.random.randint(k2, (n,), 0, n_clusters)
+    return centers[assign] + spread * jax.random.normal(k3, (n, d))
+
+
+def tune_rbf_sigma(X, k: int = 15, target_eta: float = 0.7, iters: int = 20) -> float:
+    """Bisect σ so that η = ‖K_k‖²_F / ‖K‖²_F ≈ ``target_eta`` for the RBF
+    kernel of ``X`` (the paper's Table 6 protocol)."""
+    from ..spsd import rbf_kernel_oracle  # lazy: the token pipeline needs no SPSD stack
+
+    lo, hi = 1e-6, 1e2
+    for _ in range(iters):
+        mid = float(np.sqrt(lo * hi))
+        K = rbf_kernel_oracle(X, mid)(None, None)
+        ev2 = jnp.sort(jnp.linalg.eigvalsh(K) ** 2)[::-1]
+        eta = float(jnp.sum(ev2[:k]) / jnp.sum(ev2))
+        if eta > target_eta:
+            lo = mid  # K = exp(−σ d²): η falls as σ grows
+        else:
+            hi = mid
+        if abs(eta - target_eta) < 0.05:
+            return mid
+    return float(np.sqrt(lo * hi))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,7 +1,7 @@
 """Logical-axis sharding rules (MaxText-style) → PartitionSpecs per tensor.
 
 The mesh is (pod?, data, model). Policy knobs per arch live in
-``ParallelismRules``; the §Perf hillclimb edits these, not model code.
+``ParallelismRules``, not in model code.
 
 Conventions:
 * TP ("model" axis): attention q/o width, FFN hidden, MoE expert dim,

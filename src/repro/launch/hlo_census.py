@@ -22,11 +22,10 @@ the product of enclosing trip counts. It reports:
 
 All numbers are per-device (the SPMD module is the per-device program).
 
-:func:`stream_scan_hlo` / :func:`census_stream_program` extend the census
-to arbitrary compiled *streaming* programs: they lower the engine's
-``scan_chunk`` / ``scan_panels`` for a given state and operand, so the
-fused-vs-unfused scan bodies become comparable committed numbers
-(HBM bytes per panel; gated by ``tools/census_check.py``).
+These are structural counts of a program, not timings: the tests read them
+to check what a compiled program holds (a scan's trip-weighted flops, the
+wire bytes of the compressed gradient all-reduce). Speed is measured on the
+chip by ``bench/``.
 """
 
 from __future__ import annotations
@@ -342,20 +341,17 @@ def _instr_traffic(ins: Instruction, type_of: Dict[str, str], comps: Optional[Di
     return 0.0
 
 
-def census(hlo: str, entry: Optional[str] = None) -> dict:
-    """Loop-aware census of ``hlo``. ``entry`` overrides the root computation
-    (default: the module's ENTRY) — pass a while-loop *body* to census one
-    iteration of that loop (e.g. one panel of a streaming scan)."""
+def census(hlo: str) -> dict:
+    """Loop-aware census of ``hlo``, rooted at the module's ENTRY."""
     comps = parse_computations(hlo)
-    entry_name = entry
-    if entry_name is None:
-        for raw in hlo.splitlines():
-            s = raw.strip()
-            if s.startswith("ENTRY"):
-                m = _COMP_HEADER.match(s)
-                if m:
-                    entry_name = m.group(1)
-                    break
+    entry_name = None
+    for raw in hlo.splitlines():
+        s = raw.strip()
+        if s.startswith("ENTRY"):
+            m = _COMP_HEADER.match(s)
+            if m:
+                entry_name = m.group(1)
+                break
     if entry_name is None or entry_name not in comps:
         # fall back: the computation with the most instructions
         entry_name = max(comps, key=lambda c: len(comps[c].instructions))
@@ -440,99 +436,3 @@ def census(hlo: str, entry: Optional[str] = None) -> dict:
         "while_trip_counts": trip_info,
         "n_computations": len(comps),
     }
-
-
-# ---------------------------------------------------------------------------
-# streaming-program census (scan_chunk / scan_panels)
-# ---------------------------------------------------------------------------
-
-
-def stream_scan_hlo(state, A, panel: int, *, fused: bool = True, route: str = "chunk") -> str:
-    """Compiled HLO text of one streaming scan program over ``A``.
-
-    Lowers the engine's jitted scan for the given state — ``route="chunk"``
-    compiles :func:`repro.stream.engine.scan_chunk` on a chunk-shaped
-    operand (``A``'s width must be whole panels), ``route="panels"``
-    compiles :func:`repro.stream.engine.scan_panels` on the full stream
-    operand. ``fused`` selects the fused scan body vs the legacy per-panel
-    body — the pair the census compares. Lazy imports keep this module
-    importable without the streaming stack.
-    """
-    import jax  # deferred: the census parser itself is dependency-free
-
-    from ..stream import engine
-
-    if route == "panels":
-        num_panels = A.shape[1] // panel
-        lowered = jax.jit(
-            engine.scan_panels, static_argnames=("num_panels", "panel", "fused")
-        ).lower(state, A, num_panels=num_panels, panel=panel, fused=fused)
-    elif route == "chunk":
-        if A.shape[1] % panel:
-            raise ValueError(
-                f"chunk width {A.shape[1]} must be whole panels of {panel}"
-            )
-        lowered = jax.jit(engine.scan_chunk, static_argnames=("panel", "fused")).lower(
-            state, A, panel=panel, fused=fused
-        )
-    else:
-        raise ValueError(f"route must be 'chunk' or 'panels', got {route!r}")
-    return lowered.compile().as_text()
-
-
-def scan_body_computation(hlo: str, num_panels: int) -> Optional[str]:
-    """Name of the scan's while-*body* computation: the while loop whose
-    analyzed trip count equals ``num_panels`` (ties broken by body size —
-    nested helper loops of the same trip count are smaller)."""
-    comps = parse_computations(hlo)
-    best = None
-    for comp in comps.values():
-        for ins in comp.instructions:
-            if ins.kind != "while":
-                continue
-            cond = _COND.findall(ins.body)
-            trip = _trip_count(ins.body, comps.get(cond[0]) if cond else None)
-            if trip != num_panels:
-                continue
-            bodies = _CALLS.findall(ins.body)
-            if bodies and bodies[0] in comps:
-                cand = bodies[0]
-                if best is None or len(comps[cand].instructions) > len(comps[best].instructions):
-                    best = cand
-    return best
-
-
-def census_stream_program(
-    state, A, panel: int, *, fused: bool = True, route: str = "chunk"
-) -> dict:
-    """Loop-aware census of one compiled streaming scan, per-panel normalized.
-
-    Returns the :func:`census` dict plus:
-
-      * ``num_panels``
-      * ``bytes_per_panel``  — whole-program hbm_bytes / num_panels (the
-        amortized cost including any chunk-hoisted prologue work)
-      * ``scan_body_bytes_per_panel`` / ``scan_body_n_ops`` — the census of
-        ONE iteration of the scan's while body: the steady-state marginal
-        traffic per panel. This is where the fused body's win shows up —
-        the hoisted chunk sketch leaves the loop entirely — and the number
-        the ≥25 % fused-vs-unfused regression gate is on.
-
-    Committed in ``benchmarks/baselines/census_budget.json`` and gated by
-    ``make census-check``.
-    """
-    num_panels = A.shape[1] // panel
-    hlo = stream_scan_hlo(state, A, panel, fused=fused, route=route)
-    c = census(hlo)
-    c["num_panels"] = num_panels
-    c["bytes_per_panel"] = c["hbm_bytes"] / max(num_panels, 1)
-    body = scan_body_computation(hlo, num_panels)
-    if body is not None:
-        bc = census(hlo, entry=body)
-        c["scan_body_bytes_per_panel"] = bc["hbm_bytes"]
-        c["scan_body_n_ops"] = bc["n_ops"]
-    else:  # degenerate single-panel program: the whole module is the body
-        c["scan_body_bytes_per_panel"] = c["bytes_per_panel"]
-        c["scan_body_n_ops"] = c["n_ops"]
-    c["fused"] = fused
-    return c

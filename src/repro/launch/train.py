@@ -1,5 +1,5 @@
-"""End-to-end training driver (CPU-host scale; the multi-pod path is the
-same code under the production mesh via launch/dryrun.py).
+"""End-to-end training driver (CPU-host scale; a larger mesh runs the same
+code).
 
 Example — the ~100M run used by examples/train_lm.py:
 

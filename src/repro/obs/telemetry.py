@@ -12,8 +12,8 @@ three properties the rest of the repo's streaming algebra already relies on:
 
 * *opt-in and inert*: ``tel=None`` (the default) contributes no pytree
   leaves, so the scan program, donation layout and jit cache keys are
-  byte-identical to an untelemetered stream (asserted via
-  ``launch/hlo_census.py`` in ``tests/test_obs.py``);
+  byte-identical to an untelemetered stream (asserted on the compiled
+  program text in ``tests/test_obs.py``);
 * *read-only with respect to the factors*: the hook runs after the C/R/M
   updates and only writes ``tel`` — factors are bit-identical with telemetry
   on or off;
@@ -203,8 +203,7 @@ def _fold_panel(tel: TelemetryFrame, A_L, sc_a, scores, off):
     and energy mass. Returns the updated frame and the global panel id ``t``.
 
     Deliberately cheap — everything here lives in the scan carry, where ops
-    cost ~3–6× their standalone wall-time (the ≤1.3× overhead gate is the
-    budget). Score *quantiles* are therefore not computed in-scan: the raw
+    cost ~3–6× their standalone wall-time on a CPU. Score *quantiles* are therefore not computed in-scan: the raw
     ``(panel,)`` score row is stored (one dynamic-update-slice) and
     :func:`telemetry_summary` takes nearest-rank quantiles host-side. The
     estimator's ``Ψ`` update is likewise hoisted out of the scan body — the

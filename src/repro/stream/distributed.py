@@ -198,7 +198,7 @@ def _fused_simulate(state0: PanelState, A: jax.Array, ranges, panel: int) -> Pan
 
 
 def simulate_sharded_stream(
-    state0: PanelState, A: jax.Array, panel: int, num_workers: int, *, jit="scan"
+    state0: PanelState, A: jax.Array, panel: int, num_workers: int, *, jit: str = "scan"
 ) -> PanelState:
     """Run ``num_workers`` DP workers in-process and merge.
 
@@ -209,9 +209,9 @@ def simulate_sharded_stream(
 
     ``jit="scan"`` (default) runs all workers *and* the merge as one
     compiled program (:func:`_fused_simulate` — ``state0`` is consumed, per
-    the engine's donation contract); ``jit="per-panel"`` / ``jit=False``
-    keep the pre-scan driver: one python loop over workers, each worker
-    dispatching per panel — the parity oracle for the scan path.
+    the engine's donation contract); ``jit="per-panel"`` keeps the pre-scan
+    driver: one python loop over workers, each worker dispatching per panel
+    — the parity oracle for the scan path.
     """
     if int(state0.offset) != 0:
         raise ValueError(
@@ -225,7 +225,7 @@ def simulate_sharded_stream(
     if state0.ops.prep_shard is not None:
         ctx0 = state0.ops.prep_shard(ctx0, num_workers)
     state0 = dataclasses.replace(state0, ctx=ctx0)
-    if jit in ("scan", True):
+    if jit == "scan":
         with span(f"stream/{state0.ops.name}/sharded_simulate"):
             return _fused_simulate(state0, A, tuple(ranges), panel)
     shards = []

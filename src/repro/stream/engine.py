@@ -513,9 +513,7 @@ def _fused_scan(
     )
 
 
-def scan_chunk(
-    state: PanelState, A_chunk: jax.Array, panel: int, *, fused: bool = True
-) -> PanelState:
+def scan_chunk(state: PanelState, A_chunk: jax.Array, panel: int) -> PanelState:
     """Consume a pre-padded chunk (width = whole panels) via one ``lax.scan``.
 
     Traceable core of the compiled streaming path: the whole chunk becomes a
@@ -529,9 +527,8 @@ def scan_chunk(
     column — use :func:`scan_panels` when the operand is the full stream
     array (no chunk copy).
 
-    ``fused`` (static) selects the fused scan body (:func:`_fused_scan`)
-    when the ops support it; pass ``False`` to force the legacy per-panel
-    body (the census tooling compares the two compiled programs).
+    The scan body is the fused one (:func:`_fused_scan`) when the state
+    allows it (:func:`_fused_route_ok`), else the per-panel body.
     """
     num_panels = A_chunk.shape[1] // panel
     if state.ops.telemetry is not None and state.tel is not None:
@@ -548,7 +545,7 @@ def scan_chunk(
             state, tel=fold_psi_chunk(state.tel, psi_in, state.offset)
         )
 
-    if fused and _fused_route_ok(state):
+    if _fused_route_ok(state):
         return _fused_scan(state, A_chunk, 0, A_chunk, num_panels, panel)
 
     def body(st, t):
@@ -561,7 +558,7 @@ def scan_chunk(
 
 
 def scan_panels(
-    state: PanelState, A: jax.Array, num_panels: int, panel: int, *, fused: bool = True
+    state: PanelState, A: jax.Array, num_panels: int, panel: int
 ) -> PanelState:
     """Scan ``num_panels`` panels of the *full* ``A`` at the state's offset.
 
@@ -572,10 +569,9 @@ def scan_panels(
     must guarantee ``offset + num_panels·panel ≤ A.shape[1]`` — ragged
     tails go through the zero-padded :func:`scan_chunk` path instead.
 
-    ``fused`` (static) selects the fused scan body (:func:`_fused_scan`)
-    when the ops support it, with the chunk sketch applied to the dynamic
-    window ``A[:, offset : offset + num_panels·panel]``; ``False`` forces
-    the legacy per-panel body.
+    The body is chosen as in :func:`scan_chunk`; the fused body applies the
+    chunk sketch to the dynamic window ``A[:, offset : offset +
+    num_panels·panel]``.
     """
     offs = state.offset + jnp.arange(num_panels, dtype=jnp.int32) * panel
     if state.ops.telemetry is not None and state.tel is not None:
@@ -590,7 +586,7 @@ def scan_panels(
             state, tel=fold_psi_chunk(state.tel, block, state.offset)
         )
 
-    if fused and _fused_route_ok(state):
+    if _fused_route_ok(state):
         with jax.named_scope(SCOPE_SKETCH):  # the chunk sketch's operand
             window = jax.lax.dynamic_slice_in_dim(
                 A, state.offset, num_panels * panel, axis=1
@@ -612,18 +608,18 @@ def scan_panels(
 # streaming is allocation-free in steady state. Callers must not reuse the
 # input state afterwards (see module docstring).
 _scan_stream_chunk = jax.jit(
-    scan_chunk, static_argnames=("panel", "fused"), donate_argnums=(0,)
+    scan_chunk, static_argnames=("panel",), donate_argnums=(0,)
 )
 _scan_stream_panels = jax.jit(
-    scan_panels, static_argnames=("num_panels", "panel", "fused"), donate_argnums=(0,)
+    scan_panels, static_argnames=("num_panels", "panel"), donate_argnums=(0,)
 )
 
-_JIT_MODES = ("scan", "per-panel", True, False)
+_JIT_MODES = ("scan", "per-panel")
 
 
 def stream_panels(
     state: PanelState, A: jax.Array, panel: int, *, stop: Optional[int] = None,
-    jit="scan", fused: bool = True,
+    jit: str = "scan",
 ) -> PanelState:
     """Drive columns ``[offset, stop)`` of ``A`` through the engine in
     fixed-width panels, zero-padding the ragged tail. Host-side driver:
@@ -631,23 +627,17 @@ def stream_panels(
 
     ``jit`` selects the execution strategy:
 
-    * ``"scan"`` (default, also accepts ``True``) — the whole chunk runs as
-      one compiled ``lax.scan`` program (:func:`scan_chunk`) with the input
-      state's buffers donated: no per-panel dispatch, no per-panel
-      accumulator re-materialization. The input ``state`` is *consumed*.
+    * ``"scan"`` (default) — the whole chunk runs as one compiled
+      ``lax.scan`` program (:func:`scan_chunk`) with the input state's
+      buffers donated: no per-panel dispatch, no per-panel accumulator
+      re-materialization. The input ``state`` is *consumed*.
     * ``"per-panel"`` — one :data:`jitted_panel_update` dispatch per panel
       (the pre-scan behaviour; kept as the parity oracle).
-    * ``False`` — eager per-panel execution (debugging).
 
     The tail padding is exact — not approximate — because the state's
     sketches were extended with ``pad_cols`` at init: windows past the true
     column count are zero-scaled, and the padded columns of ``A_L`` are zero,
     so the padded block contributes nothing to C, R or M.
-
-    ``fused`` (static, scan modes only) forwards to
-    :func:`scan_chunk`/:func:`scan_panels`: ``True`` (default) takes the
-    fused scan body when the ops support it, ``False`` forces the legacy
-    per-panel body.
     """
     if jit not in _JIT_MODES:
         raise ValueError(f"jit must be one of {_JIT_MODES}, got {jit!r}")
@@ -662,19 +652,16 @@ def stream_panels(
         )
     if stop <= start:
         return state
-    if jit in ("scan", True):
+    if jit == "scan":
         width = stop - start
         num_panels = padded_n(width, panel) // panel
         with span(f"stream/{state.ops.name}/scan"):
             if width == num_panels * panel:
                 # aligned: slice panels straight out of the shared A — no copy
-                return _scan_stream_panels(
-                    state, A, num_panels=num_panels, panel=panel, fused=fused
-                )
+                return _scan_stream_panels(state, A, num_panels=num_panels, panel=panel)
             chunk = A[:, start:stop]
             chunk = jnp.pad(chunk, ((0, 0), (0, num_panels * panel - width)))
-            return _scan_stream_chunk(state, chunk, panel=panel, fused=fused)
-    step = jitted_panel_update if jit == "per-panel" else panel_update
+            return _scan_stream_chunk(state, chunk, panel=panel)
     with span(f"stream/{state.ops.name}/per-panel"):
         if state.ops.telemetry is not None and state.tel is not None:
             # parity with the scan path: Ψ folds once over the consumed
@@ -690,7 +677,7 @@ def stream_panels(
             A_L = jax.lax.dynamic_slice_in_dim(A, off, width, axis=1)
             if width != panel:
                 A_L = jnp.pad(A_L, ((0, 0), (0, panel - width)))
-            state = step(state, A_L)
+            state = jitted_panel_update(state, A_L)
     return state
 
 

@@ -355,8 +355,7 @@ def run_resilient_stream(
       cadence). ``durable`` defaults to False because the subsystem's
       fault model is process death (``InjectedCrash``), where the rename
       commit alone is atomic; pass True to also survive power loss at the
-      price of an fsync per save. The ``+ckpt8`` rows of
-      ``benchmarks/stream_bench.py`` gate the cadence-8 overhead at ≤1.1×.
+      price of an fsync per save.
     """
     panel = source.panel
     if quarantine or strict:

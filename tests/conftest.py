@@ -10,11 +10,8 @@ property-based search, but it keeps the full tier-1 suite collecting and
 exercising the same assertions everywhere.
 """
 
-import os
 import sys
 import types
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))  # for `benchmarks`
 
 try:  # pragma: no cover - trivially absent on bare interpreters
     import hypothesis  # noqa: F401

@@ -331,29 +331,30 @@ def _kernel_route(on: bool):
         kops._FORCE_KERNEL_ROUTE = False
 
 
-# route -> (state, fused, kernel route forced, scopes its stream program holds)
+# route -> (state, kernel route forced, scopes its stream program holds)
 ROUTES = {
-    "fused": (_fixed_rows_state, True, False,
+    "fused": (_fixed_rows_state, False,
               {"stream.sketch", "stream.chunk_fold", "stream.mfold", "stream.admit"}),
-    "per_panel": (lambda: _fixed_state(False), False, False,
+    # an armed quarantine guard declines the fused body: the per-panel body
+    "per_panel": (lambda: engine.with_quarantine(_fixed_state(False)), False,
                   {"stream.sketch", "stream.mfold", "stream.admit", "stream.rows"}),
-    "kernel": (_gauss_state, True, True, {"stream.sketch", "stream.panel_kernel", "stream.rows"}),
+    "kernel": (_gauss_state, True, {"stream.sketch", "stream.panel_kernel", "stream.rows"}),
     # SP-SVD declares no fused hooks: the per-panel body, OSNAP on segment_sum
     # and (forced) on the countsketch kernel; other shapes than the first, as
     # the stream's jit caches its trace by shape and not by route
-    "sp_svd": (_svd_state, True, False,
+    "sp_svd": (_svd_state, False,
                {"stream.sketch", "stream.mfold", "stream.colsketch", "stream.rows"}),
-    "sp_svd_kernel": (lambda: _svd_state(c0=40, r0=40), True, True,
+    "sp_svd_kernel": (lambda: _svd_state(c0=40, r0=40), True,
                       {"stream.sketch", "stream.mfold", "stream.colsketch", "stream.rows"}),
 }
 
 
 def _stream_hlo(route: str, scan=None) -> str:
-    make, fused, kernel, _ = ROUTES[route]
+    make, kernel, _ = ROUTES[route]
     A = jax.ShapeDtypeStruct((M, N), jnp.float32)
     with _kernel_route(kernel):
         fn = scan or engine._scan_stream_panels
-        return fn.lower(make(), A, num_panels=N // PANEL, panel=PANEL, fused=fused).compile().as_text()
+        return fn.lower(make(), A, num_panels=N // PANEL, panel=PANEL).compile().as_text()
 
 
 @pytest.mark.parametrize("route", sorted(ROUTES))
@@ -362,7 +363,7 @@ def test_stream_scopes_in_compiled_hlo(route):
     program's op names: the fused CountSketch scan, the per-panel body,
     Route B's kernel (forced on the CPU), and SP-SVD's per-panel body, whose
     sketched column update is ``stream.colsketch``."""
-    assert _scopes_in(_stream_hlo(route)) == ROUTES[route][3]
+    assert _scopes_in(_stream_hlo(route)) == ROUTES[route][2]
 
 
 @pytest.mark.parametrize("route", sorted(ROUTES))
@@ -371,10 +372,10 @@ def test_scopes_change_only_metadata(route, monkeypatch):
     it compiles to when every scope is a no-op."""
     scoped = _stream_hlo(route)
 
-    def scan_panels(state, A, num_panels, panel, *, fused=True):  # fresh: no trace cache
-        return engine.scan_panels(state, A, num_panels, panel, fused=fused)
+    def scan_panels(state, A, num_panels, panel):  # fresh: no trace cache
+        return engine.scan_panels(state, A, num_panels, panel)
 
-    bare_jit = jax.jit(scan_panels, static_argnames=("num_panels", "panel", "fused"),
+    bare_jit = jax.jit(scan_panels, static_argnames=("num_panels", "panel"),
                        donate_argnums=(0,))
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
     bare = _stream_hlo(route, bare_jit)
@@ -434,7 +435,7 @@ def test_mesh_stream_scopes_in_compiled_hlo():
 def test_scope_vocabulary_is_covered():
     """Every scope of the vocabulary is one that a test above finds in a
     compiled program, and each is ``<layer>.<stage>``."""
-    tested = set().union(*(route[3] for route in ROUTES.values()))
+    tested = set().union(*(route[2] for route in ROUTES.values()))
     assert set(SCOPES) == tested | {"stream.psum", "finalize.solve"}
     assert all(re.fullmatch(r"(?:stream|finalize)\.[a-z_]+", s) for s in SCOPES)
 

@@ -8,9 +8,9 @@ Two layers of evidence:
   kernel body, so the admission arithmetic (threshold, rank-based slot
   assignment, one-hot C scatter) is checked bit-for-bit against the
   ``top_k``/cumsum path it replaces;
-* the engine routes — the fused scan body (``fused=True`` default) and the
-  forced kernel route (``_FORCE_KERNEL_ROUTE``) — vs the per-panel oracle
-  driver on whole streams, so the megakernel's wiring into
+* the engine routes — the fused scan body (taken whenever the state
+  allows it) and the forced kernel route (``_FORCE_KERNEL_ROUTE``) — vs the
+  per-panel oracle driver on whole streams, so the megakernel's wiring into
   :mod:`repro.stream.engine` reproduces the committed factors.
 """
 
@@ -22,7 +22,7 @@ import pytest
 from repro.data.synthetic import spiked_decay_matrix
 from repro.kernels import panel_update, panel_update_ref
 from repro.stream.adaptive import adaptive_cur_init
-from repro.stream.engine import stream_panels
+from repro.stream.engine import _fused_route_ok, stream_panels
 
 from test_stream import _assert_states_close
 
@@ -131,9 +131,10 @@ def test_panel_update_bf16_inputs_fp32_accum():
 
 
 def test_fused_scan_flag_parity():
-    """``fused=False`` (legacy per-panel scan body) and ``fused=True`` (the
-    chunk-hoisted fused body) must produce identical factors and identical
-    admission decisions on an adaptive stream."""
+    """The default scan (an admission-only CountSketch state takes the
+    chunk-hoisted fused body) and the per-panel oracle driver must produce
+    identical factors and identical admission decisions on an adaptive
+    stream."""
     m, n, panel = 200, 250, 40
     B, _ = spiked_decay_matrix(jax.random.key(30), m, n)
 
@@ -143,17 +144,17 @@ def test_fused_scan_flag_parity():
             sketch="countsketch", panel=panel, panel_cap=2,
         )
 
-    legacy = stream_panels(init(), B, panel, jit="scan", fused=False)
-    fused = stream_panels(init(), B, panel, jit="scan", fused=True)
-    _assert_states_close(fused, legacy)
-    np.testing.assert_array_equal(fused.ctx.col_idx, legacy.ctx.col_idx)
-    np.testing.assert_allclose(fused.ctx.ScC, legacy.ctx.ScC, atol=2e-5)
+    assert _fused_route_ok(init())
+    oracle = stream_panels(init(), B, panel, jit="per-panel")
+    fused = stream_panels(init(), B, panel)
+    _assert_states_close(fused, oracle)
+    np.testing.assert_array_equal(fused.ctx.col_idx, oracle.ctx.col_idx)
+    np.testing.assert_allclose(fused.ctx.ScC, oracle.ctx.ScC, atol=2e-5)
 
 
 def test_evict_stream_stays_on_oracle_body():
-    """Eviction-enabled adaptive CUR (no adaptive rows) declines the fused
-    body via ``supports_fused`` — the scan route must still match the
-    per-panel driver decision-for-decision."""
+    """Eviction-enabled adaptive CUR (no adaptive rows): the scan route must
+    match the per-panel driver decision-for-decision."""
     m, n, panel = 200, 200, 40
     B, _ = spiked_decay_matrix(jax.random.key(40), m, n)
 
@@ -164,7 +165,7 @@ def test_evict_stream_stays_on_oracle_body():
         )
 
     ref = stream_panels(init(), B, panel, jit="per-panel")
-    got = stream_panels(init(), B, panel, jit="scan", fused=True)
+    got = stream_panels(init(), B, panel, jit="scan")
     _assert_states_close(got, ref)
     np.testing.assert_array_equal(got.ctx.col_idx, ref.ctx.col_idx)
     assert int(got.ctx.n_evicted) == int(ref.ctx.n_evicted)

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.common import clustered_points, tune_rbf_sigma
 from repro.core import (
     fast_spsd_wang,
     faster_spsd,
@@ -14,6 +13,7 @@ from repro.core import (
     rbf_kernel_oracle,
     spsd_error_ratio,
 )
+from repro.data import clustered_points, tune_rbf_sigma
 
 
 @pytest.fixture(scope="module")

@@ -535,6 +535,19 @@ def test_scan_parity_streaming_cur_fixed(A):
         np.testing.assert_array_equal(got.R, ref.R)
 
 
+@pytest.mark.parametrize("jit", [True, False, "eager"])
+def test_stream_panels_takes_two_jit_modes(A, jit):
+    """``jit`` names a driver, ``"scan"`` or ``"per-panel"``; anything else
+    is refused before a panel is read, in both stream drivers."""
+    ci = jnp.asarray([3, 50, 99, 120], jnp.int32)
+    st = streaming_cur_init(jax.random.key(204), M, N, ci, ci, panel=45)
+    with pytest.raises(ValueError, match="jit must be one of"):
+        stream_panels(st, A, 45, jit=jit)
+    with pytest.raises(ValueError, match="jit must be one of"):
+        simulate_sharded_stream(st, A, 45, 2, jit=jit)
+    assert int(st.offset) == 0
+
+
 def test_scan_parity_adaptive_cols_evict_rows():
     """Adaptive CUR with eviction + adaptive rows: the scan carry includes
     the whole AdaptiveCURCtx/AdaptiveRowState — admission decisions, slot

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.common import powerlaw_matrix
 from repro.core import (
     fast_sp_svd,
     practical_sp_svd,
@@ -14,6 +13,7 @@ from repro.core import (
     sp_svd_update,
     svd_error_ratio,
 )
+from repro.data import powerlaw_matrix
 
 
 @pytest.fixture(scope="module")

@@ -1,7 +1,6 @@
 """End-to-end system behaviour: train → checkpoint → restore → serve."""
 
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
@@ -65,33 +64,3 @@ def test_assigned_cell_coverage():
         "kimi-k2-1t-a32b", "deepseek-v2-lite-16b", "llama3.2-1b", "phi4-mini-3.8b",
         "mistral-nemo-12b", "musicgen-large", "llama-3.2-vision-90b",
     }
-
-
-def test_dryrun_artifacts_complete():
-    """Every runnable cell has a baseline artifact on BOTH meshes."""
-    art = os.path.join(os.path.dirname(__file__), "..", "benchmarks", "artifacts", "dryrun")
-    if not os.path.isdir(art):
-        pytest.skip("dry-run artifacts not generated in this environment")
-    missing = []
-    for arch, shape, ok in supported_cells():
-        if not ok:
-            continue
-        for mesh in ("16x16", "2x16x16"):
-            if not os.path.exists(os.path.join(art, f"{arch}__{shape}__{mesh}.json")):
-                missing.append((arch, shape, mesh))
-    assert not missing, missing
-
-
-def test_dryrun_artifacts_sane():
-    import json, glob
-
-    art = os.path.join(os.path.dirname(__file__), "..", "benchmarks", "artifacts", "dryrun")
-    if not os.path.isdir(art):
-        pytest.skip("no artifacts")
-    for path in glob.glob(os.path.join(art, "*__16x16.json")):
-        with open(path) as f:
-            r = json.load(f)
-        assert r["flops_per_device"] > 0, path
-        assert r["memory"]["peak_estimate_bytes"] > 0, path
-        if r["shape"] == "train_4k":
-            assert "all-reduce" in r["collectives"], path  # DP/TP reductions must exist

@@ -15,7 +15,7 @@ asserts the re-merged result against the all-healthy sharded run.
 
 Usage:  PYTHONPATH=src python tools/chaos_check.py [--seed N]
 Exit 0 == no divergence anywhere. Wired as ``make chaos-check`` and a CI
-step next to perf-check/obs-check.
+step.
 """
 
 from __future__ import annotations
